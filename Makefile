@@ -1,7 +1,7 @@
 .PHONY: test test-fast bench-fig8 example-serve
 
-# Tier-1 verify: full suite (property tests skip gracefully without
-# hypothesis; TPU-lowering tests skip off-TPU — see tests/README.md)
+# Tier-1 verify: full suite on the CPU (kernels interpret; the TPU compile
+# tests compile for a described v5e chip — see tests/README.md)
 test:
 	PYTHONPATH=src python -m pytest -q
 

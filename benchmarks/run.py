@@ -16,6 +16,8 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig3_topk, fig4_tree_params, fig5_latency,
                             fig6_accuracy, fig7_stochastic, fig8_throughput,
                             kernels_bench, roofline)

@@ -23,6 +23,7 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
 CLI_SOURCES = [
+    "chip_smoke.py",
     "src/repro/launch/serve.py",
     "src/repro/launch/sharded_check.py",
     "src/repro/launch/train.py",
@@ -51,6 +52,9 @@ def defined_flags():
         text = (REPO / rel).read_text()
         for m in FLAG_DEF_RE.finditer(text):
             flags.setdefault(m.group(1), []).append(rel)
+            # argparse.BooleanOptionalAction also defines --no-<flag>
+            if "BooleanOptionalAction" in text[m.end():m.end() + 80]:
+                flags.setdefault("--no-" + m.group(1)[2:], []).append(rel)
     return flags
 
 

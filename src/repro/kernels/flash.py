@@ -20,17 +20,16 @@ VMEM of a TPU core, with MXU-aligned (128-multiple) tiles.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import interpret_mode
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+NEG_INF = -1e30
 
 
 def _flash_kernel(plen_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
@@ -103,7 +102,7 @@ def flash_attention_lse(q, k, v, kv_len, qpos=None, *, k_scale=None,
                         v_scale=None, scale=None,
                         block_k: int = 512, block_q: int = 0,
                         window: int = 0, causal: bool = False,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q: [B,H,n,hd]; k/v: [B,KV,L,hd]; kv_len: () or per-row [B] int32
     valid prefix (a scalar broadcasts over the batch).
 
@@ -192,10 +191,10 @@ def flash_attention_lse(q, k, v, kv_len, qpos=None, *, k_scale=None,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(plen, q, k, v, *scale_args, qpos2)
     if qpad:
         o, m, l = o[:, :, :n0], m[:, :, :n0], l[:, :, :n0]

@@ -1,5 +1,5 @@
-"""Jit'd wrappers dispatching to the Pallas kernels (interpret=True on CPU)
-or the pure-jnp references.
+"""Jit'd wrappers dispatching to the Pallas kernels or the pure-jnp
+references.
 
 ``combine_lse`` merges partial attention results computed over disjoint KV
 sources using their log-sum-exp stats — mathematically identical to a joint
@@ -7,11 +7,9 @@ softmax over the concatenation (flash-decoding combination), which is how
 paper Algorithm 1's  softmax(concat(S_past, S_predict))  is realised
 without materialising the concat.
 
-Interpret-mode policy: the ``REPRO_KERNEL_INTERPRET`` env var sets the
-module default (``INTERPRET``), but every dispatcher also takes an
-explicit ``interpret=`` override resolved at *call time* — tests and
-benchmarks flip modes per call (or by reassigning ``ops.INTERPRET``)
-without reimporting.
+Interpret mode is not a dispatcher option: each kernel decides it from
+the platform when it is traced (``repro.kernels.interpret_mode``), so the
+kernels interpret off the TPU and never on it.
 
 Quantized paths: passing per-row ``k_scale``/``v_scale`` side tensors
 marks K/V as symmetric int8 and fuses the dequant into the kernels;
@@ -33,20 +31,10 @@ from repro.kernels import ref
 from repro.kernels.flash import flash_attention_lse
 from repro.kernels.tree_block import tree_block_attention
 
-# On a real TPU set REPRO_KERNEL_INTERPRET=0; CPU CI runs interpret mode.
-# This is only the *default* — dispatchers resolve it per call, so
-# reassigning ops.INTERPRET (or passing interpret=) needs no reimport.
-INTERPRET = os.environ.get("REPRO_KERNEL_INTERPRET", "1") != "0"
-
 # Kernel-vs-jnp policy for the fused dequant-matmul at weight-projection
 # call sites (interpret-mode Pallas is slow on CPU CI, so the jnp oracle
 # is the host default, like USE_PALLAS_ATTN for the attention paths).
 USE_PALLAS_QUANT = os.environ.get("REPRO_USE_PALLAS_QUANT", "0") == "1"
-
-
-def _interp(interpret: Optional[bool]) -> bool:
-    """Resolve the per-call override against the module default."""
-    return INTERPRET if interpret is None else bool(interpret)
 
 
 def combine_lse(parts):
@@ -69,7 +57,6 @@ def combine_lse(parts):
 def tree_attention(q, k_past, v_past, k_tree, v_tree, tree_mask, past_len,
                    *, scale=None, window: int = 0, qpos=None,
                    use_kernel: bool = True, block_k: int = 512,
-                   interpret: Optional[bool] = None,
                    k_scale=None, v_scale=None, kt_scale=None,
                    vt_scale=None):
     """Two-level tree attention — see kernels/ref.py for the oracle.
@@ -92,14 +79,13 @@ def tree_attention(q, k_past, v_past, k_tree, v_tree, tree_mask, past_len,
                 vt_scale=vt_scale, scale=scale)
         return ref.tree_attention_ref(q, k_past, v_past, k_tree, v_tree,
                                       tree_mask, past_len, scale=scale)
-    it = _interp(interpret)
     op, mp, lp = flash_attention_lse(q, k_past, v_past, past_len, qpos,
                                      k_scale=k_scale, v_scale=v_scale,
                                      scale=scale, window=window,
-                                     block_k=block_k, interpret=it)
+                                     block_k=block_k)
     ot, mt, lt = tree_block_attention(q, k_tree, v_tree, tree_mask,
                                       k_scale=kt_scale, v_scale=vt_scale,
-                                      scale=scale, interpret=it)
+                                      scale=scale)
     out = combine_lse([(op, mp, lp), (ot, mt, lt)])
     return out.astype(q.dtype)
 
@@ -107,7 +93,6 @@ def tree_attention(q, k_past, v_past, k_tree, v_tree, tree_mask, past_len,
 def paged_tree_attention(q, k_pool, v_pool, table, kt_pool, vt_pool,
                          t_table, tree_mask, past_len, *, scale=None,
                          use_kernel: bool = True,
-                         interpret: Optional[bool] = None,
                          k_scale=None, v_scale=None, kt_scale=None,
                          vt_scale=None):
     """Two-level tree attention over *paged* caches: K/V live in blocked
@@ -123,22 +108,18 @@ def paged_tree_attention(q, k_pool, v_pool, table, kt_pool, vt_pool,
             vt_scale=vt_scale, scale=scale)
     from repro.kernels.paged import (paged_flash_attention_lse,
                                      paged_tree_block_attention)
-    it = _interp(interpret)
     op, mp, lp = paged_flash_attention_lse(q, k_pool, v_pool, table,
                                            past_len, k_scale=k_scale,
-                                           v_scale=v_scale, scale=scale,
-                                           interpret=it)
+                                           v_scale=v_scale, scale=scale)
     ot, mt, lt = paged_tree_block_attention(q, kt_pool, vt_pool, t_table,
                                             tree_mask, k_scale=kt_scale,
-                                            v_scale=vt_scale, scale=scale,
-                                            interpret=it)
+                                            v_scale=vt_scale, scale=scale)
     out = combine_lse([(op, mp, lp), (ot, mt, lt)])
     return out.astype(q.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, scale=None,
                            window: int = 0, use_kernel: bool = True,
-                           interpret: Optional[bool] = None,
                            k_scale=None, v_scale=None):
     """Flash-decode over a paged KV cache: pools [Nb,KV,page,hd] +
     block table [B,mb]; ``kv_len`` scalar or per-row [B]."""
@@ -155,26 +136,22 @@ def paged_decode_attention(q, k_pool, v_pool, table, kv_len, *, scale=None,
     o, _, _ = paged_flash_attention_lse(q, k_pool, v_pool, table, kv_len,
                                         qpos, k_scale=k_scale,
                                         v_scale=v_scale, scale=scale,
-                                        window=window,
-                                        interpret=_interp(interpret))
+                                        window=window)
     return o.astype(q.dtype)
 
 
 def prefill_attention(q, k, v, positions, *, scale=None, window: int = 0,
-                      block_k: int = 512, block_q: int = 512,
-                      interpret: Optional[bool] = None):
+                      block_k: int = 512, block_q: int = 512):
     """Causal flash attention for prefill/training — q: [B,H,S,hd],
     k/v: [B,KV,S,hd], positions: [S]."""
     o, _, _ = flash_attention_lse(
         q, k, v, k.shape[2], positions, scale=scale, window=window,
-        causal=True, block_k=block_k, block_q=min(block_q, q.shape[2]),
-        interpret=_interp(interpret))
+        causal=True, block_k=block_k, block_q=min(block_q, q.shape[2]))
     return o.astype(q.dtype)
 
 
 def decode_attention(q, k, v, kv_len, *, scale=None, window: int = 0,
                      use_kernel: bool = True, block_k: int = 512,
-                     interpret: Optional[bool] = None,
                      k_scale=None, v_scale=None):
     """Single-/few-token decode over a long KV cache (optionally int8
     with per-row ``k_scale``/``v_scale`` [B,KV,Lmax] dequantized
@@ -190,14 +167,13 @@ def decode_attention(q, k, v, kv_len, *, scale=None, window: int = 0,
     qpos = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32) - 1, (n,))
     o, _, _ = flash_attention_lse(q, k, v, kv_len, qpos, k_scale=k_scale,
                                   v_scale=v_scale, scale=scale,
-                                  window=window, block_k=block_k,
-                                  interpret=_interp(interpret))
+                                  window=window, block_k=block_k)
     return o.astype(q.dtype)
 
 
 def dequant_matmul(x, w_q, w_scale, *, use_kernel: Optional[bool] = None,
-                   interpret: Optional[bool] = None, block_m: int = 128,
-                   block_n: int = 128, block_k: int = 128):
+                   block_m: int = 128, block_n: int = 128,
+                   block_k: int = 128):
     """Fused dequant-matmul: x [M,K] f32 @ int8 w_q [K,N] with
     per-out-channel f32 scales [N] -> [M,N] f32.  ``use_kernel=None``
     follows the ``USE_PALLAS_QUANT`` module policy."""
@@ -206,12 +182,10 @@ def dequant_matmul(x, w_q, w_scale, *, use_kernel: Optional[bool] = None,
     if not use_kernel:
         return ref.dequant_matmul_ref(x, w_q, w_scale)
     return qz.dequant_matmul_kernel(x, w_q, w_scale, block_m=block_m,
-                                    block_n=block_n, block_k=block_k,
-                                    interpret=_interp(interpret))
+                                    block_n=block_n, block_k=block_k)
 
 
-def quant_matmul(x, w, *, use_kernel: Optional[bool] = None,
-                 interpret: Optional[bool] = None):
+def quant_matmul(x, w, *, use_kernel: Optional[bool] = None):
     """Apply a quantized weight dict ``{"q8", "scale"}`` to ``x``,
     contracting x's trailing axes with w's leading (first
     ``q8.ndim - scale.ndim``) axes — the generalised einsum every
@@ -224,5 +198,5 @@ def quant_matmul(x, w, *, use_kernel: Optional[bool] = None,
     batch = x.shape[:x.ndim - nin]
     y = dequant_matmul(x.reshape(-1, kdim).astype(jnp.float32),
                        q8.reshape(kdim, -1), scale.reshape(-1),
-                       use_kernel=use_kernel, interpret=interpret)
+                       use_kernel=use_kernel)
     return y.reshape(*batch, *out_shape)

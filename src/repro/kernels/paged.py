@@ -30,13 +30,15 @@ block*, so ``_paged_tree_kernel`` is the running-accumulation
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash import NEG_INF, _CompilerParams, _flash_kernel
+from repro.kernels import interpret_mode
+from repro.kernels.flash import NEG_INF, _flash_kernel
 
 
 def _paged_flash_kernel(plen_ref, tab_ref, *args, **kw):
@@ -50,7 +52,7 @@ def _paged_flash_kernel(plen_ref, tab_ref, *args, **kw):
 def paged_flash_attention_lse(q, k_pool, v_pool, table, kv_len, qpos=None, *,
                               k_scale=None, v_scale=None, scale=None,
                               window: int = 0, causal: bool = False,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """q: [B,H,n,hd]; k_pool/v_pool: [Nb,KV,page,hd]; table: [B,mb] int32;
     kv_len: () or per-row [B] int32 valid prefix.  k_scale/v_scale
     [Nb,KV,page] mark the pools as per-row symmetric int8.  Returns
@@ -123,10 +125,10 @@ def paged_flash_attention_lse(q, k_pool, v_pool, table, kv_len, qpos=None, *,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(plen, table, q, k_pool, v_pool, *scale_args, qpos2)
     return o, m, l
 
@@ -155,7 +157,7 @@ def _paged_tree_kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *rest,
     if quant:
         k = k * ks_ref[0, 0][:, None]
         v = v * vs_ref[0, 0][:, None]
-    mask = mask_ref[0] != 0                              # [n, page]
+    mask = mask_ref[0, 0] != 0                           # [n, page]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = jnp.where(mask, s, NEG_INF)
@@ -185,7 +187,7 @@ def _paged_tree_kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *rest,
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def paged_tree_block_attention(q, k_pool, v_pool, table, tree_mask, *,
                                k_scale=None, v_scale=None, scale=None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Paged tree-suffix attention: q [B,H,n,hd]; k/v pools
     [Nb,KV,page,hd] indexed by ``table`` [B,mb]; tree_mask [n,T] or
     per-row [B,n,T] bool over the *logical* tree positions
@@ -204,6 +206,9 @@ def paged_tree_block_attention(q, k_pool, v_pool, table, tree_mask, *,
     pad = mb * page - t
     if pad:
         mask_i8 = jnp.pad(mask_i8, ((0, 0), (0, 0), (0, pad)))
+    # one [n, page] mask tile per logical block, block-major, so each
+    # tile's last two dims are whole array dims (the TPU tiling rule)
+    mask_i8 = mask_i8.reshape(b, n, mb, page).transpose(0, 2, 1, 3)
     table = jnp.asarray(table, jnp.int32)
 
     kv_spec = pl.BlockSpec(
@@ -232,7 +237,8 @@ def paged_tree_block_attention(q, k_pool, v_pool, table, tree_mask, *,
                 kv_spec,
                 kv_spec,
                 # the mask indexes LOGICAL blocks (not through the table)
-                pl.BlockSpec((1, n, page), lambda i, j, kb, *_: (i, 0, kb)),
+                pl.BlockSpec((1, 1, n, page),
+                             lambda i, j, kb, *_: (i, kb, 0, 0)),
                 *scale_specs,
             ],
             out_specs=[
@@ -250,8 +256,8 @@ def paged_tree_block_attention(q, k_pool, v_pool, table, tree_mask, *,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(table, q, k_pool, v_pool, mask_i8, *scale_args)
     return o, m, l

@@ -29,17 +29,16 @@ memory bus, fp32 math on the MXU.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-Q_MAX = 127.0
+from repro.kernels import interpret_mode
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+Q_MAX = 127.0
 
 
 def quantize_rows(x, axis: int = -1):
@@ -112,7 +111,7 @@ def _dq_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref):
                                              "interpret"))
 def dequant_matmul_kernel(x, w_q, w_scale, *, block_m: int = 128,
                           block_n: int = 128, block_k: int = 128,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """x [M, K] f32 @ int8 w_q [K, N] with per-out-channel f32 scales [N]
     -> [M, N] f32.  The weight stays int8 on the bus; the scale applies
     once per output tile after the fp32 accumulation (same association as
@@ -140,8 +139,8 @@ def dequant_matmul_kernel(x, w_q, w_scale, *, block_m: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x.astype(jnp.float32), w_q, w_scale.reshape(1, -1))
     return out[:m0, :n0]

@@ -9,17 +9,16 @@ with the past half (``kernels.flash``).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import interpret_mode
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+NEG_INF = -1e30
 
 
 def _tree_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, quant):
@@ -52,7 +51,8 @@ def _tree_kernel(q_ref, k_ref, v_ref, mask_ref, *rest, scale, quant):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def tree_block_attention(q, k_tree, v_tree, tree_mask, *, k_scale=None,
-                         v_scale=None, scale=None, interpret: bool = True):
+                         v_scale=None, scale=None,
+                         interpret: Optional[bool] = None):
     """q: [B,H,n,hd]; k/v_tree: [B,KV,T,hd]; tree_mask: [n,T] bool, or
     per-row [B,n,T] (SpecPipe-DB fused dispatch: each batch row is a
     different request's tree, so each row carries its own ancestor mask).
@@ -97,8 +97,8 @@ def tree_block_attention(q, k_tree, v_tree, tree_mask, *, k_scale=None,
             pl.BlockSpec((1, 1, n, 128), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k_tree, v_tree, mask_i8, *scale_args)
     return o, m, l

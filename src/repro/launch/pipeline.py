@@ -116,28 +116,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-try:                                   # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                    # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-_SHARD_MAP_KW = inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` wrapper translating check_vma/check_rep across
-    jax versions.
-    """
-    # jax renamed check_rep -> check_vma; translate for older versions
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_KW:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, **kwargs)
+from jax.sharding import SingleDeviceSharding
 
 from repro.models import attention as attn_mod
 from repro.models import transformer as tf
@@ -176,6 +161,11 @@ def stage_params(cfg: ModelConfig, params, n_stages: int):
     """
     lps, padded = stage_layout(cfg, n_stages)
     reps = tf.layout(cfg)[1]
+    valid = (jnp.arange(padded) < reps).reshape(n_stages, lps)
+    if lps == 1 and padded == reps:
+        # one layer per stage: the stack already IS the stage layout, and
+        # a stack placed by ``init_stage_placed`` stays where it is
+        return [params["stack"]], valid
 
     def reshape(x):
         pad = padded - reps
@@ -186,8 +176,76 @@ def stage_params(cfg: ModelConfig, params, n_stages: int):
 
     stacked = jax.tree.map(reshape, params["stack"])
     layers = [jax.tree.map(lambda t: t[:, l], stacked) for l in range(lps)]
-    valid = (jnp.arange(padded) < reps).reshape(n_stages, lps)
     return layers, valid
+
+
+def make_stage_mesh(n_stages: int, devices=None):
+    """The ("data"=1, "model"=n_stages) mesh of the one-stage-per-device
+    deployment.  Its axes are Auto: the pipeline programs leave sharding
+    propagation to the compiler, and JAX's default explicit axes would
+    type every intermediate with a sharding those programs never name."""
+    auto = (jax.sharding.AxisType.Auto,) * 2
+    return jax.make_mesh((1, n_stages), ("data", "model"), axis_types=auto,
+                         devices=devices)
+
+
+def stage_devices(mesh):
+    """The mesh's devices in stage order (stage k runs on entry k)."""
+    return list(mesh.devices.reshape(-1))
+
+
+def init_stage_placed(key, cfg: ModelConfig, mesh, dtype=jnp.float32):
+    """Random-init params for the one-stage-per-device deployment, each
+    piece created on the device that runs it: stage k's layers on stage
+    device k (the stack is one array sharded over "model"), ``embed`` on
+    the first stage's device, ``final_norm`` and ``lm_head`` on the
+    last's.  No device ever holds the whole model.  Values match
+    ``tf.init_model(key, cfg)`` up to rounding."""
+    devs = stage_devices(mesh)
+    n_prefix, reps, tail = tf.layout(cfg)
+    if n_prefix or tail or reps % len(devs):
+        raise ValueError("placed init needs a uniform layer stack that "
+                         "divides evenly over the stages")
+    lps = reps // len(devs)
+    shards = [jax.jit(functools.partial(tf.init_stack, cfg=cfg, lo=k * lps,
+                                        hi=(k + 1) * lps, dtype=dtype),
+                      out_shardings=SingleDeviceSharding(d))(key)
+              for k, d in enumerate(devs)]
+    stacked = NamedSharding(mesh, P("model"))
+    stack = jax.tree.map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (reps, *xs[0].shape[1:]), stacked, list(xs)), *shards)
+
+    def head(names, dev):
+        return jax.jit(lambda k: {n: v for n, v in
+                                  tf.init_model(k, cfg, dtype).items()
+                                  if n in names},
+                       out_shardings=SingleDeviceSharding(dev))(key)
+
+    return {"stack": stack, **head(("embed",), devs[0]),
+            **head(("final_norm", "lm_head"), devs[-1])}
+
+
+def head_params(params, cfg: ModelConfig):
+    """The leaves ``tf._logits`` reads: final norm + unembedding table."""
+    table = "embed" if cfg.tie_embeddings else "lm_head"
+    return {"final_norm": params["final_norm"], table: params[table]}
+
+
+def prefill_slot(stage_prefill, stage_p, valid_row, kv, x, slot):
+    """One stage's admission prefill of ONE slot: the prompt activations
+    ``x`` [1, L, d] run through this stage's layers in chunk mode from
+    offset 0, writing row ``slot`` of its [slots, rows, ...] caches."""
+    row = [jax.tree.map(
+        lambda t: jax.lax.dynamic_slice_in_dim(t, slot, 1, 0), c)
+        for c in kv]
+    row, x = stage_prefill(stage_p, valid_row, row, x,
+                           jnp.ones((1,), bool), jnp.zeros((1,), jnp.int32))
+    kv = [jax.tree.map(
+        lambda t, r: jax.lax.dynamic_update_slice_in_dim(
+            t, r.astype(t.dtype), slot, 0), c, nr)
+        for c, nr in zip(kv, row)]
+    return kv, x
 
 
 def init_stage_caches(cfg: ModelConfig, pcfg: PipelineConfig,
@@ -620,3 +678,48 @@ def make_pipeline_verify(cfg: ModelConfig, pcfg: PipelineConfig, mesh,
         return exit_out["act"], exit_out["valid"], tree_kv
 
     return verify
+
+
+def make_pipeline_prefill(cfg: ModelConfig, pcfg: PipelineConfig, mesh):
+    """Separate-dispatch admission prefill through the sharded stages (the
+    flush executor's ``prefill``): ONE compiled dispatch carries one
+    slot's embedded prompt [1, L, d] from stage to stage over the
+    ``ppermute`` ring, each stage writing its own cache rows, so no device
+    ever needs another stage's layers.
+
+    Returns ``prefill(stage_p, stage_valid, model_kv, x, slot) ->
+    (new model_kv, hidden [1, L, d] after the last stage)``.
+    """
+    s_axis = "model"
+    n_stages = pcfg.n_stages
+    _, _, stage_prefill = make_stage_fns(cfg, pcfg)
+    perm = [(i, i + 1) for i in range(n_stages - 1)]
+
+    def prefill(stage_p, stage_valid, model_kv, x, slot):
+        def body(stage_p, stage_valid, model_kv, x, slot):
+            sp = [jax.tree.map(lambda t: t[0], lp) for lp in stage_p]
+            sv = stage_valid[0]
+            kv = [jax.tree.map(lambda t: t[0], lc) for lc in model_kv]
+            idx = jax.lax.axis_index(s_axis)
+            for hop in range(n_stages):
+                # only the stage holding the prompt this hop computes
+                kv, x = jax.lax.cond(
+                    idx == hop,
+                    lambda kv_, x_: prefill_slot(stage_prefill, sp, sv, kv_,
+                                                 x_, slot),
+                    lambda kv_, x_: (kv_, x_), kv, x)
+                if hop < n_stages - 1:
+                    x = jax.lax.ppermute(x, s_axis, perm)
+            last = (idx == n_stages - 1).astype(x.dtype)
+            return ([jax.tree.map(lambda t: t[None], lc) for lc in kv],
+                    jax.lax.psum(x * last, s_axis))
+
+        kv_spec = jax.tree.map(lambda _: P(s_axis), model_kv)
+        return shard_map(
+            body, mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(s_axis), stage_p), P(s_axis),
+                      kv_spec, P(), P()),
+            out_specs=(kv_spec, P()), check_vma=False,
+        )(stage_p, stage_valid, model_kv, x, slot)
+
+    return prefill

@@ -3,6 +3,13 @@ requests through the ServingEngine in pp, pipedec, or pipedec-db mode.
 
   PYTHONPATH=src python -m repro.launch.serve --mode pipedec --requests 4
 
+The pair defaults to the reduced smoke widths; ``--no-smoke`` serves the
+paper's published widths (Llama-3.1-70B target, Llama-3.2-1B draft), and
+``--target-layers`` / ``--draft-layers`` cut their depth to fit a chip:
+
+  PYTHONPATH=src python -m repro.launch.serve --mode pipedec-db \
+      --no-smoke --target-layers 1 --draft-layers 2
+
 SpecPipe-DB on the sharded pipeline deployment (one stage per device;
 combine with XLA_FLAGS=--xla_force_host_platform_device_count=8 on CPU):
 
@@ -38,30 +45,45 @@ from repro import configs as cfg_reg
 from repro.checkpoint import load_pytree
 from repro.core.pipedec import PipeDecConfig
 from repro.core.speculative import ModelBundle
+from repro.launch import pipeline as pl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.serving import Request, ServingEngine
 
+MAX_LEN = 512
+PROMPT_LEN = 8
+
 
 def build_bundle(arch: str, *, smoke: bool, seed: int, ckpt: str = "",
-                 vocab_floor: int = 0):
+                 vocab_floor: int = 0, layers: int = 0, stage_mesh=None):
     """Init (or load from ``ckpt``) one arch and wrap it as a
     ``ModelBundle`` with jitted prefill/decode/tree_verify.
+
+    ``layers`` cuts the depth (0 keeps the config's; widths never
+    change).  Random weights are created by one compiled program, so a
+    full-width layer is written once, without eager temporaries.  With
+    ``stage_mesh`` the weights are created already placed over the
+    one-stage-per-device mesh (``launch.pipeline.init_stage_placed``):
+    no device ever holds the whole model.
     """
     cfg = cfg_reg.get_config(arch, smoke=smoke)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
     if vocab_floor and cfg.vocab_size < vocab_floor:
         cfg = dataclasses.replace(cfg, vocab_size=vocab_floor)
+    key = jax.random.PRNGKey(seed)
     if ckpt:
         params = load_pytree(ckpt)["params"]
+    elif stage_mesh is not None:
+        params = pl.init_stage_placed(key, cfg, stage_mesh)
     else:
-        params = tf.init_model(jax.random.PRNGKey(seed), cfg)
+        params = jax.jit(tf.init_model, static_argnums=1)(key, cfg)
     return ModelBundle(params, cfg)
 
 
-def main(argv=None):
-    """CLI entry: build target+draft bundles, pick the executor backend
-    (``--executor local|sharded|async``), run the engine, print
-    per-request results and DB stats.
-    """
+def parse_args(argv=None):
+    """The serving CLI's options (``main`` and ``chip_smoke.py`` share
+    them)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["pp", "pipedec", "pipedec-db"],
                     default="pipedec")
@@ -79,7 +101,14 @@ def main(argv=None):
                          "device count")
     ap.add_argument("--target-arch", default="pipedec-target")
     ap.add_argument("--draft-arch", default="pipedec-draft")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced smoke widths (default); --no-smoke "
+                         "serves the published widths")
+    ap.add_argument("--target-layers", type=int, default=0,
+                    help="cut the target to this many layers (0 = all)")
+    ap.add_argument("--draft-layers", type=int, default=0,
+                    help="cut the draft to this many layers (0 = all)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--stages", type=int, default=4)
@@ -98,54 +127,97 @@ def main(argv=None):
                          "each request's horizon instead of max_len)")
     ap.add_argument("--page-size", type=int, default=16,
                     help="rows per KV block under --paged (power of two)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    target = build_bundle(args.target_arch, smoke=args.smoke, seed=0)
-    draft = build_bundle(args.draft_arch, smoke=args.smoke, seed=1)
+
+def stage_mesh(args):
+    """The mesh a multi-device pipeline executor serves from (one stage
+    per device), or None where the target lives whole on one device."""
+    n = len(jax.devices())
+    if args.mode != "pipedec-db" or args.executor == "local" or n == 1:
+        return None
+    return pl.make_stage_mesh(n)
+
+
+def build_bundles(args, mesh=None):
+    """Target + draft as the CLI serves them: the target placed over
+    ``mesh`` when one is given, the draft whole on the default device."""
+    target = build_bundle(args.target_arch, smoke=args.smoke, seed=0,
+                          layers=args.target_layers, stage_mesh=mesh)
+    draft = build_bundle(args.draft_arch, smoke=args.smoke, seed=1,
+                         layers=args.draft_layers)
     if args.quant == "int8":
         target, draft = target.quantize(), draft.quantize()
-    if args.overlap:
-        assert args.mode == "pipedec-db" and args.executor == "sharded", \
-            "--overlap needs --mode pipedec-db --executor sharded"
+    return target, draft
+
+
+def build_engine(args, target, draft, mesh=None):
+    """The executor (None for the batch modes) and the ``ServingEngine``
+    the CLI runs."""
+    if mesh is not None:
+        # one pipeline stage per mesh device
+        args.stages = mesh.shape["model"]
+    elif args.overlap:
         # the overlapped ring length is pcfg.n_stages — it must equal the
         # mesh's stage count (one device per stage)
         args.stages = len(jax.devices())
+    assert not args.overlap or (args.mode == "pipedec-db"
+                                and args.executor == "sharded"), \
+        "--overlap needs --mode pipedec-db --executor sharded"
     pcfg = PipeDecConfig(n_stages=args.stages, width=args.width,
                          branch=args.branch)
     executor = None
-    if args.mode == "pipedec-db" and args.executor == "async":
-        assert not args.paged, \
-            "--executor async has no paged path yet (use --executor " \
-            "sharded --paged)"
-        from repro.serving import AsyncPipelineExecutor
-        executor = AsyncPipelineExecutor(
-            target, draft, slots=args.slots, max_len=512,
-            tree_capacity=pcfg.tree_buffer_capacity,
-            capacity=pcfg.capacity, n_stages=args.stages)
-    elif args.mode == "pipedec-db" and args.executor == "sharded":
-        from repro.serving import (OverlappedShardedExecutor,
+    if args.mode == "pipedec-db":
+        from repro.serving import (AsyncPipelineExecutor,
+                                   LocalFusedExecutor,
+                                   OverlappedShardedExecutor,
                                    ShardedPipelineExecutor)
-        cls = OverlappedShardedExecutor if args.overlap \
-            else ShardedPipelineExecutor
-        executor = cls(
-            target, draft, slots=args.slots, max_len=512,
-            tree_capacity=pcfg.tree_buffer_capacity,
-            capacity=pcfg.capacity, n_stages=len(jax.devices()),
-            paged=args.paged, page=args.page_size)
-    elif args.mode == "pipedec-db" and args.paged:
-        from repro.serving import LocalFusedExecutor
-        executor = LocalFusedExecutor(
-            target, draft, slots=args.slots, max_len=512,
-            tree_capacity=pcfg.tree_buffer_capacity,
-            capacity=pcfg.capacity, paged=True, page=args.page_size)
+        kw = dict(slots=args.slots, max_len=MAX_LEN,
+                  tree_capacity=pcfg.tree_buffer_capacity,
+                  capacity=pcfg.capacity)
+        if args.executor == "async":
+            assert not args.paged, \
+                "--executor async has no paged path yet (use --executor " \
+                "sharded --paged)"
+            executor = AsyncPipelineExecutor(
+                target, draft, n_stages=args.stages,
+                devices=None if mesh is None else pl.stage_devices(mesh),
+                **kw)
+        elif args.executor == "sharded":
+            cls = OverlappedShardedExecutor if args.overlap \
+                else ShardedPipelineExecutor
+            executor = cls(target, draft, n_stages=len(jax.devices()),
+                           mesh=mesh, paged=args.paged, page=args.page_size,
+                           **kw)
+        else:
+            executor = LocalFusedExecutor(target, draft, paged=args.paged,
+                                          page=args.page_size, **kw)
     engine = ServingEngine(
         target, draft, mode=args.mode, max_batch=args.slots,
-        pipedec=pcfg, executor=executor)
+        max_len=MAX_LEN, pipedec=pcfg, executor=executor)
+    return executor, engine
+
+
+def submit_requests(engine, args, vocab_size: int) -> None:
+    """``--requests`` seeded random prompts of ``PROMPT_LEN`` tokens."""
     rng = np.random.default_rng(0)
     for uid in range(args.requests):
-        prompt = rng.integers(0, target.cfg.vocab_size,
-                              size=8).astype(np.int32)
+        prompt = rng.integers(0, vocab_size,
+                              size=PROMPT_LEN).astype(np.int32)
         engine.submit(Request(uid, prompt, args.new_tokens))
+
+
+def main(argv=None):
+    """CLI entry: build target+draft bundles, pick the executor backend
+    (``--executor local|sharded|async``), run the engine, print
+    per-request results and DB stats.
+    """
+    enable_compile_cache()
+    args = parse_args(argv)
+    mesh = stage_mesh(args)
+    target, draft = build_bundles(args, mesh)
+    executor, engine = build_engine(args, target, draft, mesh)
+    submit_requests(engine, args, target.cfg.vocab_size)
     results = engine.run()
     if args.executor == "async" and executor is not None:
         executor.shutdown()

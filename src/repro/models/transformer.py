@@ -105,6 +105,17 @@ def _init_unit(key, cfg: ModelConfig, *, use_moe: bool, dtype,
     ]
 
 
+def init_stack(key, cfg: ModelConfig, lo: int, hi: int, dtype=jnp.float32):
+    """Repeated units ``lo..hi-1`` of the layer stack, stacked on a leading
+    dim.  ``key`` is the model's key, so these are the values
+    ``init_model`` gives them and a deployment can create each pipeline
+    stage's share on its own device."""
+    rk = jax.random.split(jax.random.split(key, 8)[3], layout(cfg)[1])
+    units = [_init_unit(rk[i], cfg, use_moe=cfg.moe is not None,
+                        dtype=dtype) for i in range(lo, hi)]
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *units)
+
+
 def init_model(key, cfg: ModelConfig, dtype=jnp.float32):
     n_prefix, reps, tail = layout(cfg)
     ks = jax.random.split(key, 8)
@@ -122,10 +133,7 @@ def init_model(key, cfg: ModelConfig, dtype=jnp.float32):
             for i in range(n_prefix)
         ]
     if reps:
-        rk = jax.random.split(ks[3], reps)
-        units = [_init_unit(rk[i], cfg, use_moe=cfg.moe is not None,
-                            dtype=dtype) for i in range(reps)]
-        params["stack"] = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
+        params["stack"] = init_stack(key, cfg, 0, reps, dtype)
     if tail:
         params["tail"] = _init_unit(ks[4], cfg, use_moe=cfg.moe is not None,
                                     dtype=dtype, kinds=tail)

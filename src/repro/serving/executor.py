@@ -87,6 +87,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.speculative import ModelBundle, remap_tree_caches
 from repro.launch import pipeline as pl
@@ -123,6 +124,16 @@ def _paginate_full(cache, table, page: int):
 
     return jax.tree_util.tree_map_with_path(
         conv, cache, is_leaf=lambda x: x is None)
+
+
+def _stage_share(x, k: int, device):
+    """Stage ``k``'s [1, ...] slice of a stage-major leaf, on ``device``:
+    the shard already there when the leaf is placed over the stages (no
+    copy), otherwise a copy of the slice."""
+    for shard in x.addressable_shards:
+        if shard.device == device and shard.index[0] == slice(k, k + 1):
+            return shard.data
+    return jax.device_put(x[k:k + 1], device)
 
 
 class PipelineExecutor:
@@ -296,14 +307,14 @@ class LocalFusedExecutor(PipelineExecutor):
 _remap_rows_jit = jax.jit(tf.remap_tree_cache_rows)
 
 
-def _sharded_verify_impl(params, stage_p, stage_valid, model_kv, tree_kv,
-                         node_tokens, node_positions, tree_mask, write_idx,
-                         model_len, row_on, *, bucket, cfg, verify_pass):
-    """ONE compiled dispatch: embed the bucketed entry rows, flush them
-    through every pipeline stage (``make_pipeline_verify``), unembed the
-    exiting activations, scatter the updated tree-cache rows back.
-    ``params`` carries only the embed/final-norm/unembed leaves (the layer
-    stack already rides in ``stage_p``).
+def _sharded_verify_impl(stage_p, stage_valid, model_kv, tree_kv, x,
+                         node_positions, tree_mask, write_idx, model_len,
+                         row_on, *, bucket, verify_pass):
+    """ONE compiled mesh dispatch: flush the bucketed, already embedded
+    entry rows ``x`` [bucket, w, d] through every pipeline stage
+    (``make_pipeline_verify``) and scatter the updated tree-cache rows
+    back.  Returns the exiting activations; the caller unembeds them on
+    the last stage's device, where the head lives.
 
     Paged stage arenas gather their bucketed dense views HERE — inside
     this one compiled dispatch but outside the shard_map'd flush (a
@@ -322,7 +333,7 @@ def _sharded_verify_impl(params, stage_p, stage_valid, model_kv, tree_kv,
     mkv_v = [rows(c) for c in model_kv]
     tkv_v = [rows(c) for c in tree_kv]
     entry = {
-        "act": embed(params["embed"], sl(node_tokens)),
+        "act": x,
         "positions": sl(node_positions),
         "mask": sl(tree_mask),
         "write_idx": sl(write_idx),
@@ -332,7 +343,6 @@ def _sharded_verify_impl(params, stage_p, stage_valid, model_kv, tree_kv,
     exit_act, _, tkv_b = verify_pass(
         stage_p, stage_valid, [paging.densify(c) for c in mkv_v],
         [paging.densify(c) for c in tkv_v], entry)
-    logits = tf._logits(params, cfg, exit_act)
 
     def put_back(full_c, view_c, upd_c):
         def f(full, view, upd):
@@ -348,7 +358,20 @@ def _sharded_verify_impl(params, stage_p, stage_valid, model_kv, tree_kv,
 
     new_tree_kv = [put_back(f, v, u)
                    for f, v, u in zip(tree_kv, tkv_v, tkv_b)]
-    return logits, new_tree_kv
+    return exit_act, new_tree_kv
+
+
+def _sharded_prefill_impl(stage_p, stage_valid, model_kv, x, slot, *,
+                          prefill_pass):
+    """ONE compiled mesh dispatch for a slot's admission prefill: the
+    embedded prompt ``x`` [1, L, d] crosses the stages
+    (``make_pipeline_prefill``), each writing its own cache rows.  Paged
+    arenas gather dense views here and scatter back at exit."""
+    new_kv, hidden = prefill_pass(stage_p, stage_valid,
+                                  [paging.densify(c) for c in model_kv], x,
+                                  slot)
+    return [paging.repaginate(v, c) for v, c in zip(model_kv, new_kv)], \
+        hidden
 
 
 class ShardedPipelineExecutor(PipelineExecutor):
@@ -365,6 +388,14 @@ class ShardedPipelineExecutor(PipelineExecutor):
     hidden states are unembedded into the verify logits.  The draft model
     (small, replicated) verifies/proposes through the same local fused
     dispatch the ``LocalFusedExecutor`` uses.
+
+    Placement: each stage's layers stay on their stage's device (a target
+    built by ``launch.pipeline.init_stage_placed`` is never gathered), the
+    embedding table lives on the first stage's device and the unembedding
+    head on the last's; tokens are embedded there, the mesh program moves
+    activations only, and the exit is unembedded on the last device.
+    Admission prefill crosses the stages the same way
+    (``make_pipeline_prefill``), so no device needs the whole target.
     """
 
     def __init__(self, target: ModelBundle, draft: ModelBundle, *,
@@ -380,7 +411,7 @@ class ShardedPipelineExecutor(PipelineExecutor):
         assert width >= 1, "tree_capacity must include the width-w slack"
         if mesh is None:
             n = n_stages or len(jax.devices())
-            mesh = jax.make_mesh((1, n), ("data", "model"))
+            mesh = pl.make_stage_mesh(n)
         self.mesh = mesh
         self.n_stages = mesh.shape["model"]
         assert n_stages is None or n_stages == self.n_stages, \
@@ -391,6 +422,15 @@ class ShardedPipelineExecutor(PipelineExecutor):
         self.lps, self._padded = pl.stage_layout(target.cfg, self.n_stages)
         self.stage_p, self.stage_valid = pl.stage_params(
             target.cfg, target.params, self.n_stages)
+        devs = pl.stage_devices(mesh)
+        self._last = devs[-1]
+        self._replicated = NamedSharding(mesh, P())
+        self._embed_p = jax.device_put(target.params["embed"], devs[0])
+        self._head_p = jax.device_put(
+            pl.head_params(target.params, target.cfg), self._last)
+        self._embed_j = jax.jit(embed)
+        cfg = target.cfg
+        self._logits_j = jax.jit(lambda p, x: tf._logits(p, cfg, x))
         self.model_kv, self.tree_kv = pl.init_stage_caches(
             target.cfg, self.plcfg, dtype, batch=slots)
         self._d_cache = draft.init_cache(slots, max_len)
@@ -413,17 +453,15 @@ class ShardedPipelineExecutor(PipelineExecutor):
             self._d_tree = _paginate_full(self._d_tree, tt, self.page)
         self.arena = SlotPool(slots)
 
-        # only the embed table + final norm + unembed head ride the
-        # per-timestep dispatch — the layer stack is already duplicated
-        # into the stage-sharded ``stage_p`` layout
-        self._head_params = {
-            k: target.params[k] for k in ("embed", "final_norm", "lm_head")
-            if k in target.params}
         verify_pass = pl.make_pipeline_verify(target.cfg, self.plcfg, mesh,
                                               dtype)
         self._verify = jax.jit(functools.partial(
-            _sharded_verify_impl, cfg=target.cfg, verify_pass=verify_pass),
+            _sharded_verify_impl, verify_pass=verify_pass),
             static_argnames=("bucket",))
+        self._prefill = jax.jit(functools.partial(
+            _sharded_prefill_impl,
+            prefill_pass=pl.make_pipeline_prefill(target.cfg, self.plcfg,
+                                                  mesh)))
         self._commit = jax.jit(functools.partial(self._commit_impl,
                                                  cfg=target.cfg))
 
@@ -441,41 +479,25 @@ class ShardedPipelineExecutor(PipelineExecutor):
                                      commit_mask)
                 for mkv, tkv in zip(model_kv, tree_kv)]
 
-    def _scatter_prefill(self, stacked_cache, slot: int) -> None:
-        """Scatter a freshly prefilled stacked-layout model cache
-        ([reps, 1, rows, ...] per unit sub-layer) into the stage arena at
-        ``slot`` — layer ``s*lps + l`` lands in stage ``s``, in-stage
-        index ``l`` (the ``stage_params`` layout)."""
-        reps = tf.layout(self.target.cfg)[1]
-        pad = self._padded - reps
+    def _embed(self, tokens):
+        """Embed on the first stage's device, then hand the activations
+        to every stage (the mesh programs take them replicated)."""
+        return jax.device_put(self._embed_j(self._embed_p, tokens),
+                              self._replicated)
 
-        def scatter(l):
-            def f(dst, src):
-                if dst is None:
-                    return None
-                src = src[:, 0]                       # [reps, rows, ...]
-                if pad:
-                    src = jnp.concatenate(
-                        [src, jnp.zeros((pad, *src.shape[1:]), src.dtype)],
-                        0)
-                src = src.reshape(self.n_stages, self.lps,
-                                  *src.shape[1:])[:, l]  # [S, rows, ...]
-                if paging.is_paged(dst):
-                    return paging.write_slot_rows(dst, src[:, None], slot)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    dst, src[:, None].astype(dst.dtype), slot, axis=1)
-            return jax.tree_util.tree_map(
-                f, self.model_kv[l], stacked_cache,
-                is_leaf=lambda x: x is None or paging.is_paged(x))
-
-        self.model_kv = [scatter(l) for l in range(self.lps)]
+    def _unembed(self, act):
+        """Verify logits from exit activations, on the last stage's
+        device where the head lives."""
+        return self._logits_j(self._head_p, jax.device_put(act, self._last))
 
     # -- interface ------------------------------------------------------
     def prefill(self, slot: int, prompt):
-        t_cache = self.target.init_cache(1, self.max_len)
-        t_logits, t_cache = self.target.prefill(prompt, t_cache)
-        # the pure-stack arch has exactly one attention sub-layer per unit
-        self._scatter_prefill(t_cache["stack"][0], slot)
+        x = tf._embed_inputs({"embed": self._embed_p}, self.target.cfg,
+                             jnp.asarray(prompt), self.target.prefix_embeds)
+        self.model_kv, hidden = self._prefill(
+            self.stage_p, self.stage_valid, self.model_kv,
+            jax.device_put(x, self._replicated), jnp.int32(slot))
+        t_logits = self._unembed(hidden[:, -1])
         d_view = tf.slice_cache_rows(self._d_cache, slot, 1)
         _, d_row = self.draft.prefill(prompt, paging.densify(d_view))
         if paging.any_paged(d_view):
@@ -486,11 +508,12 @@ class ShardedPipelineExecutor(PipelineExecutor):
     def verify_rows(self, tokens, positions, masks, model_len, write_idx,
                     row_on):
         nb = self._bucket(int(np.max(np.nonzero(np.asarray(row_on))[0])) + 1)
-        v_all, self.tree_kv = self._verify(
-            self._head_params, self.stage_p, self.stage_valid,
-            self.model_kv, self.tree_kv, tokens, positions, masks,
+        exit_act, self.tree_kv = self._verify(
+            self.stage_p, self.stage_valid, self.model_kv, self.tree_kv,
+            self._embed(np.asarray(tokens)[:nb]), positions, masks,
             write_idx, model_len, jnp.asarray(np.asarray(row_on)),
             bucket=nb)
+        v_all = self._unembed(exit_act)
         d_all, self._d_tree = self._draft_verify(tokens, positions, masks,
                                                  model_len, write_idx,
                                                  row_on)
@@ -547,28 +570,25 @@ class ShardedPipelineExecutor(PipelineExecutor):
         self.calls["remap_rows"] += 1
 
 
-def _overlap_tick_impl(params, d_params, stage_p, stage_valid, model_kv,
-                       tree_kv, ring, d_cache, node_tokens, node_positions,
-                       tree_mask, write_idx, model_len, entry_on,
-                       entry_version, p_tokens, p_len, p_on, p_off,
+def _overlap_tick_impl(stage_p, stage_valid, model_kv, tree_kv, ring, x,
+                       node_positions, tree_mask, write_idx, model_len,
+                       entry_on, entry_version, p_x, p_len, p_on, p_off,
                        ctrl_commit, ctrl_len, ctrl_imap, ctrl_clear,
-                       ctrl_active, kill, *, cfg, d_cfg, tick, prefill_cap):
-    """ONE steady-state ring tick: ingest the batched entry layer into
-    stage 0, apply the (gated) pruning-propagation ctrl at whichever
-    stage it reached this tick, advance every in-flight layer — and the
-    prefill lane — one stage, and unembed the exiting activations into
-    verify logits.  ``params`` carries only the embed/final-norm/unembed
-    leaves (the layer stack already rides in ``stage_p``).
+                       ctrl_active, kill, *, tick, prefill_cap):
+    """ONE steady-state ring tick on the mesh: ingest the batched,
+    already embedded entry layer ``x`` into stage 0, apply the (gated)
+    pruning-propagation ctrl at whichever stage it reached this tick, and
+    advance every in-flight layer — and the prefill lane — one stage.
+    Returns the exiting activations; the caller unembeds them on the last
+    stage's device, where the head lives.
 
-    Admission prefill rides the SAME dispatch: ONE prompt chunk (up to
-    ``prefill_cap`` tokens, written at per-slot cache offset ``p_off``)
-    enters the ring's prefill lane and the replicated draft's matching
-    chunk prefill runs here beside the sharded tick (gated on "any
-    prefill entering"), so admitting a request of ANY prompt length
-    costs zero extra dispatches — long prompts stream chunk by chunk
-    over consecutive ticks.  The whole pytree state (``model_kv``/
-    ``tree_kv``/``ring``/``d_cache``) is donated by the caller so XLA
-    updates the buffers in place.
+    Admission prefill rides the SAME dispatch: ONE embedded prompt chunk
+    ``p_x`` (up to ``prefill_cap`` tokens, written at per-slot cache
+    offset ``p_off``) enters the ring's prefill lane, so admitting a
+    request of ANY prompt length costs the ring zero extra dispatches —
+    long prompts stream chunk by chunk over consecutive ticks.  The
+    stage-resident state (``model_kv``/``tree_kv``/``ring``) is donated by
+    the caller so XLA updates the buffers in place.
 
     Paged arenas gather dense views here — inside this one compiled
     dispatch but outside the shard_map'd tick (``Paged`` pool/table axes
@@ -579,12 +599,8 @@ def _overlap_tick_impl(params, d_params, stage_p, stage_valid, model_kv,
         mkv_v, tkv_v = model_kv, tree_kv
         model_kv = [paging.densify(c) for c in model_kv]
         tree_kv = [paging.densify(c) for c in tree_kv]
-    paged_d = paging.any_paged(d_cache)
-    if paged_d:
-        dc_v = d_cache
-        d_cache = paging.densify(d_cache)
     entry = {
-        "act": embed(params["embed"], node_tokens),
+        "act": x,
         "positions": node_positions,
         "mask": tree_mask,
         "write_idx": write_idx,
@@ -597,46 +613,29 @@ def _overlap_tick_impl(params, d_params, stage_p, stage_valid, model_kv,
             "active": ctrl_active}
     pentry = None
     if prefill_cap:
-        pentry = {"act": embed(params["embed"], p_tokens), "len": p_len,
-                  "on": p_on, "off": p_off}
+        pentry = {"act": p_x, "len": p_len, "on": p_on, "off": p_off}
     model_kv, tree_kv, ring, exit_out = tick(
         stage_p, stage_valid, model_kv, tree_kv, ring, entry, kill, ctrl,
         pentry)
-    logits = tf._logits(params, cfg, exit_out["act"])
-    p_logits = p_valid = None
-    if prefill_cap:
-        # unembed the prefill exit only on the (rare) ticks one actually
-        # exits — p_last is garbage otherwise and the [B,d]x[d,V] matmul
-        # would be pure steady-state waste
-        p_valid = exit_out["p_valid"]
-        p_logits = jax.lax.cond(
-            jnp.any(p_valid),
-            lambda x: tf._logits(params, cfg, x),
-            lambda x: jnp.zeros(
-                (x.shape[0], cfg.vocab_size), x.dtype),
-            exit_out["p_last"])
-        # the replicated draft prefills the entering prompt chunks inside
-        # this same compiled dispatch (its caches are slot-stacked, so
-        # one batched chunk pass covers every joining slot; the chunk
-        # writes land at each slot's own ``p_off`` offset, rows beyond
-        # the prompt length are never attended, and non-entering slots
-        # keep their buffers bit-unchanged)
-        d_cache = jax.lax.cond(
-            jnp.any(p_on),
-            lambda dc: tf.where_cache_rows(
-                p_on,
-                tf.prefill_chunk(d_params, d_cfg, p_tokens, dc, p_off)[1],
-                dc),
-            lambda dc: dc,
-            d_cache)
     if paged_t:
         model_kv = [paging.repaginate(v, c)
                     for v, c in zip(mkv_v, model_kv)]
         tree_kv = [paging.repaginate(v, c) for v, c in zip(tkv_v, tree_kv)]
-    if paged_d:
-        d_cache = paging.repaginate(dc_v, d_cache)
-    return (model_kv, tree_kv, ring, d_cache, logits, exit_out["valid"],
-            exit_out["version"], p_logits, p_valid)
+    return (model_kv, tree_kv, ring, exit_out["act"], exit_out["valid"],
+            exit_out["version"], exit_out.get("p_last"),
+            exit_out.get("p_valid"))
+
+
+def _draft_chunk_impl(d_params, p_tokens, d_cache, p_on, p_off, *, d_cfg):
+    """The replicated draft's prefill of the entering prompt chunks,
+    dispatched beside the ring tick: its caches are slot-stacked, so one
+    batched chunk pass covers every joining slot; the chunk writes land
+    at each slot's own ``p_off``, rows beyond the prompt length are never
+    attended, and non-entering slots keep their buffers bit-unchanged."""
+    dc = paging.densify(d_cache)
+    dc = tf.where_cache_rows(
+        p_on, tf.prefill_chunk(d_params, d_cfg, p_tokens, dc, p_off)[1], dc)
+    return paging.repaginate(d_cache, dc)
 
 
 class DeferredLogits:
@@ -674,7 +673,8 @@ class DeferredPrefill:
     which point the engine finishes the request's ``init_state`` with
     the resolved last-position logits.  A ``kill`` of the slot while the
     prompt is still riding marks the future dead — it will never
-    resolve and must not be consumed."""
+    resolve and must not be consumed.  ``AsyncPipelineExecutor.prefill``
+    uses the same future for the prompt riding its stage actors."""
 
     __slots__ = ("slot", "_value", "dead")
 
@@ -726,11 +726,10 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         ``prefill_cap``-token chunks that enter the tick's prefill lane
         on consecutive ticks as a special layer kind (version-bumped
         slot, dead tree exit), each chunk writing the stage caches at
-        its own per-slot offset (``p_off`` ring metadata), and BOTH
-        models' chunk prefills ride the same compiled dispatch — the
-        target stage by stage around the ring, the replicated draft
-        beside it — so admission at ANY prompt length issues no
-        separate prefill dispatch and never idles the ring.  Returns a
+        its own per-slot offset (``p_off`` ring metadata) — the target
+        stage by stage inside the tick, the replicated draft in a chunk
+        pass beside it — so admission at ANY prompt length issues no
+        separate target prefill and never idles the ring.  Returns a
         ``DeferredPrefill`` future resolved at the FINAL chunk's exit
         tick; ``None`` only when the lane is disabled
         (``prefill_cap == 0``).
@@ -785,13 +784,16 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
                                   prefill_cap=self.prefill_cap)
         tick = pl.make_pipedec_tick(target.cfg, self.plcfg, self.mesh,
                                     prefill_cap=self.prefill_cap)
-        impl = functools.partial(
-            _overlap_tick_impl, cfg=target.cfg, d_cfg=draft.cfg, tick=tick,
-            prefill_cap=self.prefill_cap)
-        # donate the persistent state pytrees (model_kv, tree_kv, ring,
-        # d_cache) so XLA aliases them through the tick in place
+        impl = functools.partial(_overlap_tick_impl, tick=tick,
+                                 prefill_cap=self.prefill_cap)
+        # donate the persistent state pytrees (model_kv, tree_kv, ring;
+        # d_cache through the draft's chunk pass) so XLA aliases them in
+        # place
         self._tick = jax.jit(
-            impl, donate_argnums=(4, 5, 6, 7) if self.donate else ())
+            impl, donate_argnums=(2, 3, 4) if self.donate else ())
+        self._draft_chunk = jax.jit(
+            functools.partial(_draft_chunk_impl, d_cfg=draft.cfg),
+            donate_argnums=(2,) if self.donate else ())
         # per-slot tree version counters + outstanding-flight futures
         self._versions = np.zeros((slots,), np.int32)
         self._handles = [collections.deque() for _ in range(slots)]
@@ -878,18 +880,22 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         and prefill entries) and resolve the futures of every layer —
         and every prefill — that exited."""
         ctrl_active = self._ctrl_active or not self.gate_ctrl
-        (self.model_kv, self.tree_kv, self._ring, self._d_cache,
-         exit_logits, exit_valid, exit_version, p_logits,
-         p_valid) = self._tick(
-            self._head_params, self.draft.params, self.stage_p,
-            self.stage_valid, self.model_kv, self.tree_kv, self._ring,
-            self._d_cache, tokens, positions, masks, write_idx, model_len,
-            jnp.asarray(np.asarray(row_on)), jnp.asarray(self._versions),
-            jnp.asarray(self._p_tokens), jnp.asarray(self._p_len),
+        p_x = self._embed(self._p_tokens) if self.prefill_cap else None
+        (self.model_kv, self.tree_kv, self._ring, exit_act, exit_valid,
+         exit_version, p_last, p_valid) = self._tick(
+            self.stage_p, self.stage_valid, self.model_kv, self.tree_kv,
+            self._ring, self._embed(np.asarray(tokens)), positions, masks,
+            write_idx, model_len, jnp.asarray(np.asarray(row_on)),
+            jnp.asarray(self._versions), p_x, jnp.asarray(self._p_len),
             jnp.asarray(self._p_on), jnp.asarray(self._p_off),
             jnp.asarray(self._ctrl_commit), jnp.asarray(self._ctrl_len),
             jnp.asarray(self._ctrl_imap), jnp.asarray(self._ctrl_clear),
             jnp.asarray(ctrl_active), jnp.asarray(self._kill_mask))
+        if self._p_on.any():
+            self._d_cache = self._draft_chunk(
+                self.draft.params, jnp.asarray(self._p_tokens),
+                self._d_cache, jnp.asarray(self._p_on),
+                jnp.asarray(self._p_off))
         if ctrl_active and counter == "pipeline_tick":
             # drain ticks are counted separately — the ctrl-active rate
             # (ctrl_active_ticks / pipeline_tick) prices steady state only
@@ -908,6 +914,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         self.calls[counter] += 1
 
         ev, evers = np.asarray(exit_valid), np.asarray(exit_version)
+        exit_logits = self._unembed(exit_act) if ev.any() else None
         for slot in np.nonzero(ev)[0]:
             q = self._handles[int(slot)]
             if not q:
@@ -921,7 +928,8 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
                     f"{int(evers[slot])}")
             h._value = exit_logits[slot]
 
-        if self.prefill_cap:
+        if self.prefill_cap and np.asarray(p_valid).any():
+            p_logits = self._unembed(p_last)
             for slot in np.nonzero(np.asarray(p_valid))[0]:
                 s = int(slot)
                 if s not in self._p_exits:
@@ -1170,12 +1178,12 @@ class AsyncPipelineExecutor(PipelineExecutor):
         ctrl version, neutralising the slot's in-flight ctrl wherever it
         sits; a miss does NOT (its earlier commits must finish
         propagating).
-      * ``scatter`` — admission prefill: the host prefills the target on
-        its own device (the async backend uses the separate-dispatch
-        prefill; ``prefill_cap == 0``) and the per-stage cache rows ride
-        the pipe as one message, landing at each stage AFTER the
-        retired occupant's (suppressed) stale messages — FIFO gives the
-        recycle ordering for free.
+      * ``prefill`` — admission prefill (the async backend has no prefill
+        lane; ``prefill_cap == 0``): the embedded prompt rides the pipe
+        as one message, each stage writing the slot's cache rows with
+        its own layers, landing AFTER the retired occupant's
+        (suppressed) stale messages — FIFO gives the recycle ordering
+        for free — and the last stage returns the prompt's logits.
 
     Bit-identity argument: each stage processes one global message
     sequence FIFO, which reproduces the lockstep schedule's per-stage
@@ -1230,14 +1238,20 @@ class AsyncPipelineExecutor(PipelineExecutor):
                 jax.device_put(t[k], self._devices[k]),
                 tree, is_leaf=is_leaf)
 
+        def own_share(tree, k):
+            return jax.tree.map(
+                lambda t: _stage_share(t, k, self._devices[k]), tree)
+
         layers, valid = pl.stage_params(target.cfg, target.params,
                                         self.n_stages)
         model_kv, tree_kv = pl.init_stage_caches(target.cfg, self.plcfg,
                                                  dtype, batch=slots)
         valid = np.asarray(valid)
-        # per-stage actor state: param slices + cache slices committed to
-        # the stage's device (each list entry owned by ONE actor thread)
-        self._sp = [[put_stage(layers[l], k) for l in range(self.lps)]
+        # per-stage actor state: param slices ([1, ...] leaves, taken
+        # from the shard already on the stage's device when the target
+        # was placed) + cache slices committed to the stage's device
+        # (each list entry owned by ONE actor thread)
+        self._sp = [[own_share(layers[l], k) for l in range(self.lps)]
                     for k in range(self.n_stages)]
         self._sv = [valid[k] for k in range(self.n_stages)]
         self._kv = [[put_stage(model_kv[l], k) for l in range(self.lps)]
@@ -1251,20 +1265,22 @@ class AsyncPipelineExecutor(PipelineExecutor):
             draft.init_tree_caches(slots, tree_capacity),
             self._draft_device)
 
-        head = {k: target.params[k]
-                for k in ("embed", "final_norm", "lm_head")
-                if k in target.params}
-        self._embed_p = jax.device_put(head["embed"], self._devices[0])
-        self._head_last = jax.device_put(head, self._devices[-1])
+        self._embed_p = jax.device_put(target.params["embed"],
+                                       self._devices[0])
+        self._head_last = jax.device_put(
+            pl.head_params(target.params, target.cfg), self._devices[-1])
 
-        stage_apply, stage_ctrl, _ = pl.make_stage_fns(target.cfg,
-                                                       self.plcfg)
+        stage_apply, stage_ctrl, stage_prefill = pl.make_stage_fns(
+            target.cfg, self.plcfg)
         cfg = target.cfg
-        self._apply_j = jax.jit(stage_apply)
+        unstack = lambda sp: [jax.tree.map(lambda t: t[0], lp) for lp in sp]
+        self._apply_j = jax.jit(
+            lambda sp, *a: stage_apply(unstack(sp), *a))
         self._ctrl_j = jax.jit(stage_ctrl)
+        self._prefill_j = jax.jit(
+            lambda sp, *a: pl.prefill_slot(stage_prefill, unstack(sp), *a))
         self._embed_j = jax.jit(embed)
         self._logits_j = jax.jit(lambda p, x: tf._logits(p, cfg, x))
-        self._scatter_j = jax.jit(self._scatter_stage_impl)
 
         # per-slot versions: layer staleness (bumped on EVERY kill) vs
         # ctrl staleness (bumped only on drop_ctrl retires — a miss must
@@ -1310,20 +1326,6 @@ class AsyncPipelineExecutor(PipelineExecutor):
             for _ in range(self.n_stages)]
 
     # -- small shared helpers -------------------------------------------
-    def _scatter_stage_impl(self, kv, src_k, slot):
-        """Write one prefilled request's rows for ONE stage: ``src_k``
-        leaves are [lps, rows, ...] (this stage's slice of the stacked
-        prefill), scattered into the stage's [slots, rows, ...] arena at
-        ``slot``."""
-        out = []
-        for l in range(self.lps):
-            out.append(jax.tree_util.tree_map(
-                lambda dst, s, l=l: None if dst is None else
-                jax.lax.dynamic_update_slice_in_dim(
-                    dst, s[l][None].astype(dst.dtype), slot, axis=0),
-                kv[l], src_k, is_leaf=lambda x: x is None))
-        return out
-
     def _reset_ctrl(self) -> None:
         self._ctrl_commit = np.zeros((self.slots,), bool)
         self._ctrl_len = np.zeros((self.slots,), np.int32)
@@ -1390,8 +1392,12 @@ class AsyncPipelineExecutor(PipelineExecutor):
 
     def _consume_exit(self, msg) -> None:
         self._consumed += 1
+        if msg[0] == "exit_prefill":
+            _, _seq, logits, handle = msg
+            handle._value = logits
+            return
         if msg[0] != "exit_layer":
-            return                       # ctrl/scatter/stop pass-through
+            return                       # ctrl/stop pass-through
         _, _seq, logits, row_on, versions = msg
         self._exit_layers_consumed += 1
         for slot in np.nonzero(row_on)[0]:
@@ -1486,8 +1492,8 @@ class AsyncPipelineExecutor(PipelineExecutor):
                     msg = self._stage_layer(k, ctr, msg)
                 elif kind == "ctrl":
                     self._stage_ctrl_msg(k, ctr, msg)
-                elif kind == "scatter":
-                    self._stage_scatter(k, msg)
+                elif kind == "prefill":
+                    msg = self._stage_prefill(k, msg)
                 ctr["msgs"] += 1
                 ctr["busy_s"] += time.perf_counter() - t0
                 self._aput(out, msg)
@@ -1540,12 +1546,15 @@ class AsyncPipelineExecutor(PipelineExecutor):
             np.where(live, commit_len, 0), imap)
         ctr["ctrl_applied"] += 1
 
-    def _stage_scatter(self, k: int, msg) -> None:
-        _, _seq, slot, src = msg
-        src_k = jax.tree_util.tree_map(
-            lambda t: None if t is None else t[k], src,
-            is_leaf=lambda x: x is None)
-        self._kv[k] = self._scatter_j(self._kv[k], src_k, np.int32(slot))
+    def _stage_prefill(self, k: int, msg):
+        _, seq, slot, x, handle = msg
+        x = jax.device_put(x, self._devices[k])
+        self._kv[k], x = self._prefill_j(self._sp[k], self._sv[k],
+                                         self._kv[k], x, np.int32(slot))
+        if k == self.n_stages - 1:
+            return ("exit_prefill", seq,
+                    self._logits_j(self._head_last, x[:, -1]), handle)
+        return ("prefill", seq, slot, x, handle)
 
     def _draft_loop(self) -> None:
         try:
@@ -1607,38 +1616,22 @@ class AsyncPipelineExecutor(PipelineExecutor):
 
     # -- PipelineExecutor seam ------------------------------------------
     def prefill(self, slot: int, prompt):
-        """Separate-dispatch admission prefill (the async pipe has no
-        prefill lane): the target prefills on the host's device and the
-        per-stage cache rows ride the pipe as ONE scatter message —
-        FIFO-ordered after the retired occupant's stale messages and
-        before the new occupant's first entry; the draft prefill is a
+        """Admission prefill through the pipe (it has no prefill lane):
+        the prompt is embedded on stage 0's device and rides the stages
+        as ONE message — FIFO-ordered after the retired occupant's stale
+        messages and before the new occupant's first entry — each stage
+        writing the slot's rows with its own layers; blocks until the
+        last stage returns the prompt's logits.  The draft prefill is a
         job on the draft actor, in the same engine push order."""
-        t_cache = self.target.init_cache(1, self.max_len)
-        t_logits, t_cache = self.target.prefill(prompt, t_cache)
-        src = self._stage_src(t_cache["stack"][0])
-        self._push(("scatter", self._next_seq(), int(slot), src))
+        x = tf._embed_inputs({"embed": self._embed_p}, self.target.cfg,
+                             jnp.asarray(prompt), self.target.prefix_embeds)
+        handle = DeferredPrefill(int(slot))
+        self._push(("prefill", self._next_seq(), int(slot), x, handle))
         self._submit_draft(("prefill", int(slot),
                             np.asarray(prompt)))
-        return t_logits
-
-    def _stage_src(self, stacked_cache):
-        """Host-side reshape of a freshly prefilled stacked model cache
-        ([reps, 1, rows, ...] leaves) into per-stage slices
-        ([S, lps, rows, ...]) for the scatter message."""
-        reps = tf.layout(self.target.cfg)[1]
-        pad = self._padded - reps
-
-        def f(leaf):
-            if leaf is None:
-                return None
-            src = np.asarray(leaf)[:, 0]             # [reps, rows, ...]
-            if pad:
-                src = np.concatenate(
-                    [src, np.zeros((pad, *src.shape[1:]), src.dtype)], 0)
-            return src.reshape(self.n_stages, self.lps, *src.shape[1:])
-
-        return jax.tree_util.tree_map(f, stacked_cache,
-                                      is_leaf=lambda x: x is None)
+        while not handle.ready:
+            self._pump()
+        return handle.resolve()
 
     def tick_rows(self, tokens, positions, masks, model_len, write_idx,
                   row_on):

@@ -1,7 +1,3 @@
-import sys
-import types
-
-import jax
 import pytest
 
 from repro.models.config import (EncoderConfig, MLAConfig, ModelConfig,
@@ -9,88 +5,6 @@ from repro.models.config import (EncoderConfig, MLAConfig, ModelConfig,
 
 # CPU tests must see exactly ONE device (the dry-run sets its own 512-device
 # flag in its own process) — nothing to configure here on purpose.
-
-
-# --------------------------------------------------------------------------
-# hypothesis shim: the property-based modules (test_tree, test_speculative,
-# test_moe, test_ssm_rglru, test_serving_db, ...) import hypothesis at module
-# scope.  When it is not installed (it is a dev extra, see
-# requirements-dev.txt), install a stub into sys.modules so those modules
-# still COLLECT; every @given test then reports as skipped instead of the
-# whole module erroring out.
-# --------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
-
-    class _AnyStrategy:
-        """Chainable stand-in for hypothesis strategy objects."""
-
-        def __call__(self, *args, **kwargs):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    def _given(*gargs, **gkwargs):
-        def deco(fn):
-            # zero-arg replacement: pytest must not try to resolve the
-            # @given-provided parameters as fixtures
-            def skipper():
-                pytest.skip("hypothesis not installed "
-                            "(pip install -r requirements-dev.txt)")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
-
-    def _settings(*sargs, **skwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    _stub = types.ModuleType("hypothesis")
-    _stub.given = _given
-    _stub.settings = _settings
-    _stub.strategies = _AnyStrategy()
-    _stub.HealthCheck = _AnyStrategy()
-    _stub.assume = lambda *a, **k: True
-    _st_stub = types.ModuleType("hypothesis.strategies")
-    _st_stub.__getattr__ = lambda name: _AnyStrategy()
-    sys.modules["hypothesis"] = _stub
-    sys.modules["hypothesis.strategies"] = _st_stub
-
-
-# --------------------------------------------------------------------------
-# tpu_kernel marker: Pallas tests that LOWER for a real TPU (interpret=False)
-# only run where TPU compilation is available; their interpret-mode twins run
-# everywhere.
-# --------------------------------------------------------------------------
-def _tpu_available() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:
-        return False
-
-
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers",
-        "tpu_kernel: Pallas TPU-lowering test (auto-skipped on hosts "
-        "without TPU; interpret-mode variants cover the same math)")
-
-
-def pytest_collection_modifyitems(config, items):
-    if _tpu_available():
-        return
-    skip_tpu = pytest.mark.skip(
-        reason="TPU lowering unavailable on this host (interpret-mode "
-               "twins cover the same kernels)")
-    for item in items:
-        if "tpu_kernel" in item.keywords:
-            item.add_marker(skip_tpu)
 
 
 @pytest.fixture(scope="session")

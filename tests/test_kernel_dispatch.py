@@ -1,8 +1,8 @@
 """REPRO_USE_PALLAS_ATTN=1 path: kernel-backed decode / tree-verify must
 match the jnp path exactly (the kernels run in interpret mode on CPU).
-Plus the dispatch-policy seams: per-call ``interpret=`` overrides resolved
-at call time (no reimport), and the ``USE_PALLAS_QUANT`` kernel-vs-oracle
-policy for the fused dequant-matmul."""
+Plus the dispatch-policy seams: interpret mode chosen by platform at call
+time, and the ``USE_PALLAS_QUANT`` kernel-vs-oracle policy for the fused
+dequant-matmul."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,32 +64,29 @@ def test_kernel_tree_verify_matches_jnp(tiny_dense):
     np.testing.assert_allclose(got[:, 0], ref[:, 0], rtol=2e-4, atol=2e-4)
 
 
-def test_interpret_resolved_per_call_not_at_import():
-    """ops.INTERPRET is only the *default*: reassigning it (or passing
-    interpret=) takes effect without reimporting the module — the env var
-    must not be frozen into the dispatchers at import time."""
+def test_interpret_resolved_per_call_not_at_import(monkeypatch):
+    """Interpret mode follows the platform at call time, never an import-
+    time default: off the TPU the dispatchers interpret (and match the
+    oracle), ``interpret=False`` compiles for a described chip, and on a
+    TPU the interpreter is refused outright."""
+    from repro.kernels import interpret_mode
+
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.normal(size=(1, 2, 1, 32)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(1, 2, 16, 32)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(1, 2, 16, 32)).astype(np.float32))
     want = ref.decode_attention_ref(q, k, v, 12)
+    out = ops.decode_attention(q, k, v, 12, block_k=16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
-    old = ops.INTERPRET
-    try:
-        # on CPU, interpret=False would fail inside pallas_call — the
-        # per-call override must rescue a flipped module default...
-        ops.INTERPRET = False
-        out = ops.decode_attention(q, k, v, 12, block_k=16, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-        # ...and reassigning the module default (no reimport) must be
-        # honoured too
-        ops.INTERPRET = True
-        out = ops.decode_attention(q, k, v, 12, block_k=16)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-    finally:
-        ops.INTERPRET = old
+    assert interpret_mode() is True
+    assert interpret_mode(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    assert interpret_mode(False) is False
+    with pytest.raises(ValueError, match="never used on a TPU"):
+        interpret_mode(True)
 
 
 def test_quant_matmul_policy_kernel_vs_oracle():

@@ -101,29 +101,6 @@ def test_flash_zero_length_prefix_safe():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.tpu_kernel
-def test_flash_compiles_for_tpu():
-    """Actual TPU lowering (interpret=False) — auto-skipped off-TPU; the
-    interpret-mode sweeps above cover the same math everywhere."""
-    rng = np.random.default_rng(2)
-    q = rand(rng, (1, 4, 8, 128), jnp.float32)
-    k = rand(rng, (1, 2, 256, 128), jnp.float32)
-    v = rand(rng, (1, 2, 256, 128), jnp.float32)
-    o, m, l = flash_attention_lse(q, k, v, 200, block_k=128, interpret=False)
-    assert np.isfinite(np.asarray(o)).all()
-
-
-@pytest.mark.tpu_kernel
-def test_tree_block_compiles_for_tpu():
-    rng = np.random.default_rng(3)
-    q = rand(rng, (1, 4, 8, 128), jnp.float32)
-    kt = rand(rng, (1, 2, 16, 128), jnp.float32)
-    vt = rand(rng, (1, 2, 16, 128), jnp.float32)
-    mask = jnp.ones((8, 16), bool)
-    o, m, l = tree_block_attention(q, kt, vt, mask, interpret=False)
-    assert np.isfinite(np.asarray(o)).all()
-
-
 # ---------------------------------------------------------------------------
 # int8 quantization (KV rows + weights)
 # ---------------------------------------------------------------------------

@@ -6,7 +6,7 @@ warnings, no separate prefill dispatches, ctrl gated off on quiet ticks).
 
 All tests run on a 1-stage mesh (the in-process device budget); the same
 levers run on a REAL 8-device mesh via ``repro.launch.sharded_check
---overlap`` (see tests/test_executor_sharded.py).
+--overlap`` (see tests/test_sharded_check.py).
 """
 import warnings
 

@@ -13,9 +13,10 @@ and the in-ring commit/remap ctrl channel); the ctrl application is
 pinned bit-identical to the central ``commit_tree_nodes`` +
 ``remap_tree_cache_rows`` path the flush executor uses.  Multi-stage
 in-flight behaviour (stale layers behind a kill) runs on a REAL 8-device
-mesh via ``repro.launch.sharded_check`` (see tests/test_executor_sharded
-.py).
+mesh via ``repro.launch.sharded_check`` (see tests/test_sharded_check.py).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,7 +29,7 @@ from repro.models.layers import embed
 
 def _setup(cfg, n_stages=1):
     params = tf.init_model(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1, n_stages), ("data", "model"))
+    mesh = pl.make_stage_mesh(n_stages)
     pcfg = pl.PipelineConfig(n_stages=n_stages, width=4, tree_capacity=16,
                              max_len=32)
     sp, valid = pl.stage_params(cfg, params, n_stages)
@@ -381,3 +382,49 @@ def test_remap_tree_cache_rows_matches_per_row_reference(tiny_dense):
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a[1]), np.asarray(b[1])),
         tf.slice_cache_rows(got, 1, 1), tf.slice_cache_rows(tkv, 1, 1))
+
+
+def test_pipeline_prefill_matches_prefill(tiny_dense):
+    """The flush executor's separate-dispatch prefill crosses the stages
+    (``make_pipeline_prefill``): the slot's stage cache rows and the
+    last-position logits match ``tf.prefill`` to float rounding, and the
+    other slot's rows stay untouched."""
+    cfg = tiny_dense
+    params, mesh, pcfg, sp, valid = _setup(cfg)
+    model_kv, _ = pl.init_stage_caches(cfg, pcfg, batch=2)
+    prefill = jax.jit(pl.make_pipeline_prefill(cfg, pcfg, mesh))
+    prompt = np.asarray([5, 3, 2, 7, 11], np.int32)
+    x = embed(params["embed"], jnp.asarray(prompt)[None])
+    model_kv, hidden = prefill(sp, valid, model_kv, x, jnp.int32(1))
+
+    ref_logits, ref_cache = tf.prefill(params, cfg, jnp.asarray(prompt)[None],
+                                       tf.init_cache(cfg, 1, 32))
+    got = tf._logits(params, cfg, hidden[:, -1])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+    stacked = ref_cache["stack"][0]
+    for l in range(len(model_kv)):
+        want = jax.tree.map(lambda t, l=l: np.asarray(t[l][0]), stacked)
+        got_rows = jax.tree.map(lambda t: np.asarray(t[0, 1]), model_kv[l])
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-5), got_rows, want)
+        for leaf in jax.tree.leaves(model_kv[l]):
+            assert not np.asarray(leaf)[0, 0].any(), "slot 0 untouched"
+
+
+def test_init_stage_placed_matches_init_model(tiny_dense):
+    """Placed init gives ``init_model``'s values (up to rounding), with the
+    stack sharded over the stage mesh and the stage layout taken as is
+    when each stage holds one layer."""
+    cfg = dataclasses.replace(tiny_dense, num_layers=1)
+    mesh = pl.make_stage_mesh(1)
+    key = jax.random.PRNGKey(4)
+    placed = pl.init_stage_placed(key, cfg, mesh)
+    want = tf.init_model(key, cfg)
+    assert set(placed) == set(want)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7), placed, want)
+    layers, valid = pl.stage_params(cfg, placed, 1)
+    assert layers[0] is placed["stack"] and bool(valid.all())
+    for leaf in jax.tree.leaves(placed["stack"]):
+        assert leaf.sharding.spec[0] == "model"
