@@ -135,6 +135,5 @@ class ChainSpecEngine:
                     flights = []
                 if len(committed) >= 1 + max_new_tokens:
                     break
-            stats.commits_per_step.append(0)
 
         return np.asarray(committed[: 1 + max_new_tokens]), stats

@@ -124,7 +124,6 @@ class GenStats:
     hits: int = 0
     misses: int = 0
     entries: int = 0
-    commits_per_step: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def acceptance(self) -> float:
@@ -408,7 +407,6 @@ class PipeDecEngine:
         B=1 case."""
         st.t += 1
         st.stats.timesteps = st.t
-        step_commits = 0
 
         entry = self.gather_entry(st)
         if entry is not None:
@@ -425,10 +423,9 @@ class PipeDecEngine:
         ev = self.exit_pick(st)
         if ev is not None:
             fl, root_row = ev
-            step_commits += self.exit_apply(
+            self.exit_apply(
                 st, fl, root_row, commit_caches=self._commit_own_caches,
                 remap_caches=self._remap_own_caches)
-        st.stats.commits_per_step.append(step_commits)
         return st
 
     # ------------------------------------------------------------------

@@ -15,6 +15,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import re
 from typing import Any, Optional
 
 import jax
@@ -87,12 +88,28 @@ def _tree_verify_rows_impl(params, node_tokens, node_positions, tree_mask,
     return logits, tf.update_cache_rows(tree_caches, tc_b, 0)
 
 
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` compiled as the program ``jit_<name>`` (every
+    character that cannot be in an identifier becomes ``_``), so a device
+    trace names what ran; ``fn`` itself is left untouched."""
+    fn = functools.partial(fn)
+    fn.__name__ = re.sub(r"\W", "_", name)
+    return jax.jit(fn, **jit_kwargs)
+
+
 class ModelBundle:
     """params+cfg with jitted prefill / decode / tree-verify / commit.
 
     ``calls`` counts dispatches by closure name — the call-count hook the
     SpecPipe-DB equivalence tests use to assert the fused path issues
     exactly ONE tree-verify per model per global timestep.
+
+    Each program compiles as ``jit_<cfg.name>_<method>`` (e.g.
+    ``jit_target_prefill``), except the fused ``tree_verify_rows``, which
+    both models compile as ``jit_tree_verify_rows``: a profile tells the
+    two apart by call order (the executor launches the target's first),
+    and the four per-timestep programs then show under three names (see
+    PERF.md, §3).
     """
 
     def __init__(self, params, cfg: ModelConfig, *, enc_out=None,
@@ -104,25 +121,26 @@ class ModelBundle:
         self.window_override = window_override
         self.calls = collections.Counter()
 
-        self._prefill = jax.jit(functools.partial(
+        name = lambda method: f"{cfg.name}_{method}"  # noqa: E731
+        self._prefill = named_jit(name("prefill"), functools.partial(
             tf.prefill, cfg=cfg, prefix_embeds=prefix_embeds,
-            enc_out=enc_out, window_override=window_override),
-            static_argnames=())
-        self._decode = jax.jit(functools.partial(
+            enc_out=enc_out, window_override=window_override))
+        self._decode = named_jit(name("decode"), functools.partial(
             tf.decode_step, cfg=cfg, enc_out=enc_out,
             window_override=window_override))
-        self._tree_verify = jax.jit(functools.partial(
+        self._tree_verify = named_jit(name("tree_verify"), functools.partial(
             tf.tree_verify_step, cfg=cfg, enc_out=enc_out,
             window_override=window_override))
-        self._tree_verify_rows = jax.jit(functools.partial(
-            _tree_verify_rows_impl, cfg=cfg, enc_out=enc_out,
-            window_override=window_override),
+        self._tree_verify_rows = named_jit(
+            "tree_verify_rows", functools.partial(
+                _tree_verify_rows_impl, cfg=cfg, enc_out=enc_out,
+                window_override=window_override),
             static_argnames=("bucket",))
-        self._commit = jax.jit(functools.partial(
+        self._commit = named_jit(name("commit"), functools.partial(
             tf.commit_tree_node, cfg=cfg))
-        self._commit_rows = jax.jit(functools.partial(
+        self._commit_rows = named_jit(name("commit_rows"), functools.partial(
             tf.commit_tree_nodes, cfg))
-        self._forward = jax.jit(functools.partial(
+        self._forward = named_jit(name("forward"), functools.partial(
             tf.forward, cfg=cfg, prefix_embeds=prefix_embeds,
             enc_out=enc_out, window_override=window_override))
 

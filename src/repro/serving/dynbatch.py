@@ -64,6 +64,17 @@ Streaming: ``run(on_token=...)`` emits ``(uid, token, timestep)`` the
 timestep each token is committed (the admission timestep for the prefill
 token) instead of only at retire; the streamed prefix always equals the
 final ``Result.tokens``.
+
+Tracing: every executed timestep runs inside a ``specpipe.timestep``
+profiler span (``jax.profiler.TraceAnnotation``; metadata ``step``,
+``active``) whose children name its phases — ``specpipe.join``,
+``specpipe.admit``, ``specpipe.entry``, ``specpipe.expand``,
+``specpipe.exit``, ``specpipe.stream``, ``specpipe.retire`` — with one
+``specpipe.admit.slot`` / ``expand.slot`` / ``exit.slot`` child per
+request (metadata ``uid``, ``slot``); the executors add
+``specpipe.executor.<method>`` spans around their calls.  With no
+profiler running a span costs about a microsecond (0.8-1.3 us on a CPU
+host), some 0.1 ms of a timestep.
 """
 from __future__ import annotations
 
@@ -75,6 +86,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.dynbatch import TreeBatch
 from repro.core.pipedec import (DecodeState, EntryInputs, GenStats,
@@ -122,8 +134,12 @@ class DBStats:
     even when no entry is pending); empty on the flush/local backends.
     ``accepted`` / ``proposed`` count speculative verify decisions per
     uid (a hit accepts the drafted node, a miss falls back to the target
-    token); their totals give the run's aggregate ``acceptance_rate`` —
-    the regression currency of the int8 serving path.
+    token), folded in at retire; their totals give the run's aggregate
+    ``acceptance_rate`` — the regression currency of the int8 serving
+    path.  ``hits`` / ``misses`` count the same decisions live, as each
+    exit decides, and ``tokens_committed`` every committed token (each
+    request's first, picked from its prefill, included) as it commits:
+    they read mid-run, before any request retires.
     ``separate_prefill_dispatches`` counts admissions that ran a
     standalone ``executor.prefill`` dispatch instead of riding the ring's
     (chunked) prefill lane — exactly 0 on the overlapped backend at ANY
@@ -134,6 +150,9 @@ class DBStats:
     """
     timesteps: int = 0
     total_commits: int = 0
+    hits: int = 0
+    misses: int = 0
+    tokens_committed: int = 0
     per_request: Dict[int, GenStats] = dataclasses.field(default_factory=dict)
     occupancy: List[int] = dataclasses.field(default_factory=list)
     verify_dispatches: List[int] = dataclasses.field(default_factory=list)
@@ -352,19 +371,23 @@ class SpecPipeDBEngine:
         slot's in-flight ring layers — the pruning-propagation stage."""
         remaps: Dict[int, np.ndarray] = {}
         for slot in stepping:
-            st = active[slot].state
-            commits = 0
+            a = active[slot]
+            st = a.state
             if slot in picks:
-                fl, root_row = picks[slot]
-                misses0 = st.stats.misses
-                commits = self.inner.exit_apply(
-                    st, fl, root_row,
-                    commit_caches=lambda _st: None,  # batched above
-                    remap_caches=lambda _st, imap, s=slot:
-                        remaps.__setitem__(s, imap))
-                if kill_stale and st.stats.misses > misses0:
-                    self.executor.kill(slot)
-            st.stats.commits_per_step.append(commits)
+                with TraceAnnotation("specpipe.exit.slot", uid=a.req.uid,
+                                     slot=slot):
+                    fl, root_row = picks[slot]
+                    misses0 = st.stats.misses
+                    self.stats.tokens_committed += self.inner.exit_apply(
+                        st, fl, root_row,
+                        commit_caches=lambda _st: None,  # batched above
+                        remap_caches=lambda _st, imap, s=slot:
+                            remaps.__setitem__(s, imap))
+                    missed = st.stats.misses > misses0
+                    self.stats.misses += missed
+                    self.stats.hits += not missed
+                    if kill_stale and missed:
+                        self.executor.kill(slot)
             self.trees.set_row(slot, st.tree)
             st.tree = None
         if remaps:
@@ -385,19 +408,29 @@ class SpecPipeDBEngine:
         # phase 1: stacked gather-entry, ONE fused verify per model (the
         # pending flag alone decides participation — the entry inputs come
         # from the stacked TreeBatch views, not a per-slot gather)
-        pending = self._bump(active, stepping)
-        if pending:
-            self._fused_entry(active, pending)
-        self.stats.verify_dispatches.append(1 if pending else 0)
+        with TraceAnnotation("specpipe.entry"):
+            pending = self._bump(active, stepping)
+            if pending:
+                self._fused_entry(active, pending)
+            self.stats.verify_dispatches.append(1 if pending else 0)
 
-        # expansion per slot (tree ops only; may defer at the caps)
-        for slot in stepping:
-            self.inner.maybe_expand(active[slot].state)
+        self._expand(active, stepping)
 
         # phase 2: exit — batched commit, then batched prune/remap
-        picks = self._pick_exits(active, stepping)
-        self._commit_exits(active, picks)
-        self._apply_exits(active, stepping, picks)
+        with TraceAnnotation("specpipe.exit"):
+            picks = self._pick_exits(active, stepping)
+            self._commit_exits(active, picks)
+            self._apply_exits(active, stepping, picks)
+
+    def _expand(self, active: Dict[int, _Active],
+                stepping: List[int]) -> None:
+        """Expansion per slot (tree ops only; may defer at the caps)."""
+        with TraceAnnotation("specpipe.expand"):
+            for slot in stepping:
+                a = active[slot]
+                with TraceAnnotation("specpipe.expand.slot", uid=a.req.uid,
+                                     slot=slot):
+                    self.inner.maybe_expand(a.state)
 
     # ------------------------------------------------------------------
     def _advance_overlapped(self, active: Dict[int, _Active],
@@ -412,31 +445,32 @@ class SpecPipeDBEngine:
         at exit time.  Misses/retires kill the slot's in-flight layers
         in-ring; commits and prune maps are queued as the next tick's
         ctrl message, trailing the in-flight layers stage by stage."""
-        pending = self._bump(active, stepping)
-        if pending:
-            rows = self._entry_rows(active, pending)
-        else:
-            rows = (*self.executor.dead_entry,
-                    np.zeros((self.max_slots,), bool), None)
-        tokens, positions, masks, mlen, wi, row_on, _ = rows
+        with TraceAnnotation("specpipe.entry"):
+            pending = self._bump(active, stepping)
+            if pending:
+                rows = self._entry_rows(active, pending)
+            else:
+                rows = (*self.executor.dead_entry,
+                        np.zeros((self.max_slots,), bool), None)
+            tokens, positions, masks, mlen, wi, row_on, _ = rows
 
-        # phase 1: ONE ring tick — entry for t in, exit for
-        # t - (n_stages - 1) out
-        d_all, handles = self.executor.tick_rows(tokens, positions, masks,
-                                                 mlen, wi, row_on)
-        self.stats.verify_dispatches.append(1 if pending else 0)
-        self.stats.tick_dispatches.append(1)
-        self._apply_entries(active, pending, rows,
-                            lambda slot: handles[slot], d_all)
+            # phase 1: ONE ring tick — entry for t in, exit for
+            # t - (n_stages - 1) out
+            d_all, handles = self.executor.tick_rows(
+                tokens, positions, masks, mlen, wi, row_on)
+            self.stats.verify_dispatches.append(1 if pending else 0)
+            self.stats.tick_dispatches.append(1)
+            self._apply_entries(active, pending, rows,
+                                lambda slot: handles[slot], d_all)
 
-        for slot in stepping:
-            self.inner.maybe_expand(active[slot].state)
+        self._expand(active, stepping)
 
         # phase 2: exit — this tick's resolved futures; cache sync rides
         # the NEXT tick's ctrl (draft applies immediately)
-        picks = self._pick_exits(active, stepping)
-        self._commit_exits(active, picks)
-        self._apply_exits(active, stepping, picks, kill_stale=True)
+        with TraceAnnotation("specpipe.exit"):
+            picks = self._pick_exits(active, stepping)
+            self._commit_exits(active, picks)
+            self._apply_exits(active, stepping, picks, kill_stale=True)
 
     # ------------------------------------------------------------------
     def _stream(self, active: Dict[int, _Active], now: int,
@@ -446,12 +480,13 @@ class SpecPipeDBEngine:
         token budget, mirroring ``DecodeState.output``)."""
         if on_token is None:
             return
-        for a in active.values():
-            limit = 1 + a.state.max_new_tokens
-            fresh = a.state.committed[a.emitted:limit]
-            for tok in fresh:
-                on_token(a.req.uid, int(tok), now)
-            a.emitted += len(fresh)
+        with TraceAnnotation("specpipe.stream"):
+            for a in active.values():
+                limit = 1 + a.state.max_new_tokens
+                fresh = a.state.committed[a.emitted:limit]
+                for tok in fresh:
+                    on_token(a.req.uid, int(tok), now)
+                a.emitted += len(fresh)
 
     # ------------------------------------------------------------------
     def run(self, key: Optional[jax.Array] = None,
@@ -459,11 +494,9 @@ class SpecPipeDBEngine:
         """Drive the shared pipeline schedule until queue and slots drain.
         Returns {uid: Result} (same shape as ``ServingEngine.run``).
         ``on_token(uid, token, timestep)`` streams tokens at commit time."""
-        from repro.serving.engine import Result
-
         base_key = key if key is not None else jax.random.PRNGKey(0)
         self.stats = DBStats()  # per-run aggregates (scheduler stats persist)
-        results: Dict[int, Result] = {}
+        results: Dict = {}
         active: Dict[int, _Active] = {}
         joining: Dict[int, _Joining] = {}
         ring_prefill = self.overlapped and \
@@ -477,76 +510,88 @@ class SpecPipeDBEngine:
                 nxt = self.sched.next_arrival()
                 if nxt is not None and nxt > now:
                     now = nxt
+            with TraceAnnotation("specpipe.timestep",
+                                 step=self.stats.timesteps + 1,
+                                 active=len(active)):
+                now = self._timestep(now, active, joining, results,
+                                     base_key, ring_prefill, on_token)
+            if now > guard:
+                raise RuntimeError(
+                    f"SpecPipeDBEngine exceeded timestep guard ({guard}); "
+                    f"{len(active)} active, {self.sched.pending} queued")
+        if self.overlapped:
+            # every live flight resolved during the run (retires killed the
+            # rest), so this is a no-op safety valve that leaves the
+            # executor's ring clean for the next run
+            self.executor.drain()
+        return results
 
-            # 0. join: requests whose in-ring admission prefill resolved
-            # (its last tick exited the prompt's final hidden state) seed
-            # their DecodeState from the resolved logits and go active —
-            # the same init_state path, with the prefill already done
-            for slot in [s for s in sorted(joining)
-                         if joining[s].handle.ready]:
-                j = joining.pop(slot)
-                st = self.inner.init_state(
-                    j.req.prompt, j.req.max_new_tokens, key=j.key,
-                    eos=self.eos_token,
-                    sampling=getattr(j.req, "sampling", None),
-                    prefill_fn=lambda _p, h=j.handle: h.resolve())
-                self.trees.adopt_row(slot, st.tree)
-                st.tree = None
-                active[slot] = _Active(j.req, st, j.t0)
+    def _timestep(self, now: int, active: Dict[int, _Active],
+                  joining: Dict[int, _Joining], results: Dict,
+                  base_key: jax.Array, ring_prefill: bool,
+                  on_token: Optional[Callable]) -> int:
+        """One executed global timestep (join, refill, advance, retire);
+        returns the new timestep count ``now``."""
+        from repro.serving.engine import Result
 
-            # 1. refill: join-on-prefill for arrived requests.  On the
-            # overlapped backend the prefill enters the ring inside the
-            # NEXT tick dispatch (prefill-in-ring: no separate dispatch,
-            # no idle timestep) and the request parks in ``joining``
-            # until its prompt exits the pipeline; other backends (and
-            # prompts longer than the ring's prefill lane) prefill
-            # through the executor immediately
-            for req, slot in self.sched.admit(now):
-                rkey = jax.random.fold_in(base_key, req.uid)
-                sampling = getattr(req, "sampling", None)
-                if ring_prefill:
-                    h = self.executor.begin_prefill(slot, req.prompt)
-                    if h is not None:
-                        joining[slot] = _Joining(req, rkey, h,
-                                                 time.perf_counter())
-                        continue
-                if self.fused:
-                    self.stats.separate_prefill_dispatches += 1
+        # 0. join: requests whose in-ring admission prefill resolved
+        # (its last tick exited the prompt's final hidden state) seed
+        # their DecodeState from the resolved logits and go active —
+        # the same init_state path, with the prefill already done
+        if joining:
+            with TraceAnnotation("specpipe.join"):
+                for slot in [s for s in sorted(joining)
+                             if joining[s].handle.ready]:
+                    j = joining.pop(slot)
                     st = self.inner.init_state(
-                        req.prompt, req.max_new_tokens, key=rkey,
-                        eos=self.eos_token, sampling=sampling,
-                        prefill_fn=functools.partial(
-                            self.executor.prefill, slot))
-                else:
-                    st = self.inner.init_state(
-                        req.prompt, req.max_new_tokens, key=rkey,
-                        caches=self.arena.caches(slot), eos=self.eos_token,
-                        sampling=sampling)
-                self.trees.adopt_row(slot, st.tree)
-                st.tree = None  # canonical copy lives in the TreeBatch
-                active[slot] = _Active(req, st, time.perf_counter())
-            self._stream(active, now, on_token)   # prefill (first) tokens
-
-            # 2. advance: every active request shares this timestep
-            now += 1
-            self.stats.timesteps += 1
-            stepping = [s for s in sorted(active)
-                        if not active[s].state.done]
-            if self.overlapped:
-                self._advance_overlapped(active, stepping)
-            elif self.fused:
-                self._advance_fused(active, stepping)
-            else:
-                for slot in stepping:
-                    st = active[slot].state
-                    st.tree = self.trees.get_row(slot)
-                    self.inner.step(st)
-                    self.trees.set_row(slot, st.tree)
+                        j.req.prompt, j.req.max_new_tokens, key=j.key,
+                        eos=self.eos_token,
+                        sampling=getattr(j.req, "sampling", None),
+                        prefill_fn=lambda _p, h=j.handle: h.resolve())
+                    self.stats.tokens_committed += 1
+                    self.trees.adopt_row(slot, st.tree)
                     st.tree = None
-            self._stream(active, now, on_token)   # this timestep's commits
+                    active[slot] = _Active(j.req, st, j.t0)
 
-            # 3. retire: free slots for the next refill (fused mode: the
-            # slot's caches already live in the executor's arena)
+        # 1. refill: join-on-prefill for arrived requests.  On the
+        # overlapped backend the prefill enters the ring inside the
+        # NEXT tick dispatch (prefill-in-ring: no separate dispatch,
+        # no idle timestep) and the request parks in ``joining``
+        # until its prompt exits the pipeline; other backends (and
+        # prompts longer than the ring's prefill lane) prefill
+        # through the executor immediately
+        with TraceAnnotation("specpipe.admit"):
+            for req, slot in self.sched.admit(now):
+                with TraceAnnotation("specpipe.admit.slot", uid=req.uid,
+                                     slot=slot):
+                    self._admit_one(req, slot, active, joining, base_key,
+                                    ring_prefill)
+        self._stream(active, now, on_token)   # prefill (first) tokens
+
+        # 2. advance: every active request shares this timestep
+        now += 1
+        self.stats.timesteps += 1
+        stepping = [s for s in sorted(active) if not active[s].state.done]
+        if self.overlapped:
+            self._advance_overlapped(active, stepping)
+        elif self.fused:
+            self._advance_fused(active, stepping)
+        else:
+            for slot in stepping:
+                st = active[slot].state
+                before = (st.stats.hits, st.stats.misses, st.stats.commits)
+                st.tree = self.trees.get_row(slot)
+                self.inner.step(st)
+                self.trees.set_row(slot, st.tree)
+                st.tree = None
+                self.stats.hits += st.stats.hits - before[0]
+                self.stats.misses += st.stats.misses - before[1]
+                self.stats.tokens_committed += st.stats.commits - before[2]
+        self._stream(active, now, on_token)   # this timestep's commits
+
+        # 3. retire: free slots for the next refill (fused mode: the
+        # slot's caches already live in the executor's arena)
+        with TraceAnnotation("specpipe.retire"):
             for slot in [s for s, a in active.items() if a.state.done]:
                 a = active.pop(slot)
                 st = a.state
@@ -565,23 +610,41 @@ class SpecPipeDBEngine:
                     a.req.uid, slot, now,
                     caches=None if self.fused else st.caches())
 
-            occ = len(active)
-            self.stats.occupancy.append(occ)
-            self.sched.stats.occupancy.append(occ)
-            pages = getattr(self.arena, "pages", None)
-            if pages is not None:
-                self.stats.page_counters.append(pages.counters())
-            if now > guard:
-                raise RuntimeError(
-                    f"SpecPipeDBEngine exceeded timestep guard ({guard}); "
-                    f"{len(active)} active, {self.sched.pending} queued")
-        if self.overlapped:
-            # every live flight resolved during the run (retires killed the
-            # rest), so this is a no-op safety valve that leaves the
-            # executor's ring clean for the next run
-            self.executor.drain()
-        return results
+        occ = len(active)
+        self.stats.occupancy.append(occ)
+        self.sched.stats.occupancy.append(occ)
+        pages = getattr(self.arena, "pages", None)
+        if pages is not None:
+            self.stats.page_counters.append(pages.counters())
+        return now
 
+    def _admit_one(self, req, slot: int, active: Dict[int, _Active],
+                   joining: Dict[int, _Joining], base_key: jax.Array,
+                   ring_prefill: bool) -> None:
+        """Admit one request onto ``slot``: its prefill enters the ring
+        (it parks in ``joining``) or runs through the executor now."""
+        rkey = jax.random.fold_in(base_key, req.uid)
+        sampling = getattr(req, "sampling", None)
+        if ring_prefill:
+            h = self.executor.begin_prefill(slot, req.prompt)
+            if h is not None:
+                joining[slot] = _Joining(req, rkey, h, time.perf_counter())
+                return
+        if self.fused:
+            self.stats.separate_prefill_dispatches += 1
+            st = self.inner.init_state(
+                req.prompt, req.max_new_tokens, key=rkey,
+                eos=self.eos_token, sampling=sampling,
+                prefill_fn=functools.partial(self.executor.prefill, slot))
+        else:
+            st = self.inner.init_state(
+                req.prompt, req.max_new_tokens, key=rkey,
+                caches=self.arena.caches(slot), eos=self.eos_token,
+                sampling=sampling)
+        self.stats.tokens_committed += 1
+        self.trees.adopt_row(slot, st.tree)
+        st.tree = None  # canonical copy lives in the TreeBatch
+        active[slot] = _Active(req, st, time.perf_counter())
 
 def generate_with_executor(target: ModelBundle, draft: ModelBundle,
                            pcfg: PipeDecConfig, prompt, max_new_tokens: int,

@@ -73,6 +73,11 @@ per global timestep with pending entries (flush/local), and
 (overlapped); the async backend counts entry/ctrl *messages* and
 per-stage steps instead (``calls["entry_msgs"]`` / ``calls["ctrl_msgs"]``
 / ``calls["stage_steps"]``).
+
+Every backend's public calls (``prefill``, ``begin_prefill``,
+``verify_rows``, ``tick_rows``, ``commit_rows``, ``remap_rows``) run
+inside a ``specpipe.executor.<method>`` profiler span, and every program
+an executor compiles carries a name of its own (``jit_<model>_<what>``).
 """
 from __future__ import annotations
 
@@ -87,14 +92,27 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.speculative import ModelBundle, remap_tree_caches
+from repro.core.speculative import ModelBundle, named_jit, remap_tree_caches
 from repro.launch import pipeline as pl
 from repro.models import paging
 from repro.models import transformer as tf
 from repro.models.layers import embed
 from repro.serving.scheduler import KVArena, PagedKVArena, SlotPool
+
+
+def _traced(method):
+    """Run an executor method inside a ``specpipe.executor.<name>``
+    profiler span."""
+    name = f"specpipe.executor.{method.__name__}"
+
+    @functools.wraps(method)
+    def call(self, *args, **kwargs):
+        with TraceAnnotation(name):
+            return method(self, *args, **kwargs)
+    return call
 
 
 def _full_table(slots: int, rows: int, page: int):
@@ -244,6 +262,7 @@ class LocalFusedExecutor(PipelineExecutor):
                                  max_len=max_len,
                                  tree_capacity=tree_capacity)
 
+    @_traced
     def prefill(self, slot: int, prompt):
         t_cache, d_cache, t_tree, d_tree = self.arena.caches(slot)
         t_logits, t_cache = self.target.prefill(prompt, t_cache)
@@ -257,6 +276,7 @@ class LocalFusedExecutor(PipelineExecutor):
     def _draft_tree(self):
         return self.arena.stacked[3]
 
+    @_traced
     def verify_rows(self, tokens, positions, masks, model_len, write_idx,
                     row_on):
         nb = self._bucket(int(np.max(np.nonzero(np.asarray(row_on))[0])) + 1)
@@ -270,6 +290,7 @@ class LocalFusedExecutor(PipelineExecutor):
         self.arena.set_tree_caches(t_tree, d_tree)
         return v_all, d_all
 
+    @_traced
     def commit_rows(self, model_len, commit_mask) -> None:
         node0 = jnp.zeros((self.slots,), jnp.int32)  # row 0 is the root
         t_cache, d_cache, t_tree, d_tree = self.arena.stacked
@@ -290,6 +311,7 @@ class LocalFusedExecutor(PipelineExecutor):
             tf.update_cache_rows(t_tree, t_row, slot),
             tf.update_cache_rows(d_tree, d_row, slot))
 
+    @_traced
     def remap_rows(self, index_maps, row_mask) -> None:
         """ONE batched gather per model over the slot-stacked arena
         (identity rows leave unmasked slots bit-unchanged)."""
@@ -304,7 +326,7 @@ class LocalFusedExecutor(PipelineExecutor):
 
 # one compiled batched remap shared by every backend (retraces per cache
 # pytree structure, i.e. once per model)
-_remap_rows_jit = jax.jit(tf.remap_tree_cache_rows)
+_remap_rows_jit = named_jit("remap_rows", tf.remap_tree_cache_rows)
 
 
 def _sharded_verify_impl(stage_p, stage_valid, model_kv, tree_kv, x,
@@ -428,9 +450,10 @@ class ShardedPipelineExecutor(PipelineExecutor):
         self._embed_p = jax.device_put(target.params["embed"], devs[0])
         self._head_p = jax.device_put(
             pl.head_params(target.params, target.cfg), self._last)
-        self._embed_j = jax.jit(embed)
         cfg = target.cfg
-        self._logits_j = jax.jit(lambda p, x: tf._logits(p, cfg, x))
+        self._embed_j = named_jit(f"{cfg.name}_embed", embed)
+        self._logits_j = named_jit(f"{cfg.name}_logits",
+                                   lambda p, x: tf._logits(p, cfg, x))
         self.model_kv, self.tree_kv = pl.init_stage_caches(
             target.cfg, self.plcfg, dtype, batch=slots)
         self._d_cache = draft.init_cache(slots, max_len)
@@ -455,15 +478,18 @@ class ShardedPipelineExecutor(PipelineExecutor):
 
         verify_pass = pl.make_pipeline_verify(target.cfg, self.plcfg, mesh,
                                               dtype)
-        self._verify = jax.jit(functools.partial(
-            _sharded_verify_impl, verify_pass=verify_pass),
+        prefill_pass = pl.make_pipeline_prefill(target.cfg, self.plcfg, mesh)
+        self._verify = named_jit(
+            f"{cfg.name}_pipeline_verify",
+            functools.partial(_sharded_verify_impl, verify_pass=verify_pass),
             static_argnames=("bucket",))
-        self._prefill = jax.jit(functools.partial(
-            _sharded_prefill_impl,
-            prefill_pass=pl.make_pipeline_prefill(target.cfg, self.plcfg,
-                                                  mesh)))
-        self._commit = jax.jit(functools.partial(self._commit_impl,
-                                                 cfg=target.cfg))
+        self._prefill = named_jit(
+            f"{cfg.name}_pipeline_prefill",
+            functools.partial(_sharded_prefill_impl,
+                              prefill_pass=prefill_pass))
+        self._commit = named_jit(
+            f"{cfg.name}_pipeline_commit",
+            functools.partial(self._commit_impl, cfg=target.cfg))
 
     def _draft_cache(self):
         return self._d_cache
@@ -491,6 +517,7 @@ class ShardedPipelineExecutor(PipelineExecutor):
         return self._logits_j(self._head_p, jax.device_put(act, self._last))
 
     # -- interface ------------------------------------------------------
+    @_traced
     def prefill(self, slot: int, prompt):
         x = tf._embed_inputs({"embed": self._embed_p}, self.target.cfg,
                              jnp.asarray(prompt), self.target.prefix_embeds)
@@ -505,6 +532,7 @@ class ShardedPipelineExecutor(PipelineExecutor):
         self._d_cache = tf.update_cache_rows(self._d_cache, d_row, slot)
         return t_logits
 
+    @_traced
     def verify_rows(self, tokens, positions, masks, model_len, write_idx,
                     row_on):
         nb = self._bucket(int(np.max(np.nonzero(np.asarray(row_on))[0])) + 1)
@@ -520,6 +548,7 @@ class ShardedPipelineExecutor(PipelineExecutor):
         self.calls["pipeline_verify"] += 1
         return v_all, d_all
 
+    @_traced
     def commit_rows(self, model_len, commit_mask) -> None:
         node0 = jnp.zeros((self.slots,), jnp.int32)
         self.model_kv = self._commit(self.model_kv, self.tree_kv, node0,
@@ -558,6 +587,7 @@ class ShardedPipelineExecutor(PipelineExecutor):
             self.capacity)
         return tf.update_cache_rows(self._d_tree, d_row, slot)
 
+    @_traced
     def remap_rows(self, index_maps, row_mask) -> None:
         """ONE batched gather per model: the stage-layout tree arenas
         ([S, slots, rows, ...] leaves) and the replicated draft's
@@ -789,9 +819,11 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         # donate the persistent state pytrees (model_kv, tree_kv, ring;
         # d_cache through the draft's chunk pass) so XLA aliases them in
         # place
-        self._tick = jax.jit(
-            impl, donate_argnums=(2, 3, 4) if self.donate else ())
-        self._draft_chunk = jax.jit(
+        self._tick = named_jit(
+            f"{target.cfg.name}_pipeline_tick", impl,
+            donate_argnums=(2, 3, 4) if self.donate else ())
+        self._draft_chunk = named_jit(
+            f"{draft.cfg.name}_prefill_chunk",
             functools.partial(_draft_chunk_impl, d_cfg=draft.cfg),
             donate_argnums=(2,) if self.donate else ())
         # per-slot tree version counters + outstanding-flight futures
@@ -842,6 +874,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         self._p_on[slot] = True
 
     # -- prefill-in-ring ------------------------------------------------
+    @_traced
     def begin_prefill(self, slot: int, prompt):
         """Queue ``slot``'s admission prefill into the ring: the prompt
         is split into ``prefill_cap``-token chunks that enter the
@@ -944,6 +977,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
                     del self._p_exits[s]
                     self._p_handles.pop(s)._value = p_logits[s:s + 1]
 
+    @_traced
     def tick_rows(self, tokens, positions, masks, model_len, write_idx,
                   row_on):
         """ONE ring tick for this global timestep.
@@ -972,6 +1006,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         return d_all, handles
 
     # -- PipelineExecutor seam ------------------------------------------
+    @_traced
     def verify_rows(self, tokens, positions, masks, model_len, write_idx,
                     row_on):
         """Standard seam, overlapped semantics: returns (handles, d_all)
@@ -980,6 +1015,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
                                         model_len, write_idx, row_on)
         return handles, d_all
 
+    @_traced
     def commit_rows(self, model_len, commit_mask) -> None:
         """Queue the target-side exit commit as the next tick's ctrl
         message (it must trail the in-flight layers through the ring);
@@ -1001,6 +1037,7 @@ class OverlappedShardedExecutor(ShardedPipelineExecutor):
         self._ctrl_active = True
         self._d_tree = self._draft_remap_row(slot, index_map)
 
+    @_traced
     def remap_rows(self, index_maps, row_mask) -> None:
         rm = np.asarray(row_mask)
         if not rm.any():
@@ -1274,13 +1311,16 @@ class AsyncPipelineExecutor(PipelineExecutor):
             target.cfg, self.plcfg)
         cfg = target.cfg
         unstack = lambda sp: [jax.tree.map(lambda t: t[0], lp) for lp in sp]
-        self._apply_j = jax.jit(
+        self._apply_j = named_jit(
+            f"{cfg.name}_stage_apply",
             lambda sp, *a: stage_apply(unstack(sp), *a))
-        self._ctrl_j = jax.jit(stage_ctrl)
-        self._prefill_j = jax.jit(
+        self._ctrl_j = named_jit(f"{cfg.name}_stage_ctrl", stage_ctrl)
+        self._prefill_j = named_jit(
+            f"{cfg.name}_stage_prefill",
             lambda sp, *a: pl.prefill_slot(stage_prefill, unstack(sp), *a))
-        self._embed_j = jax.jit(embed)
-        self._logits_j = jax.jit(lambda p, x: tf._logits(p, cfg, x))
+        self._embed_j = named_jit(f"{cfg.name}_embed", embed)
+        self._logits_j = named_jit(f"{cfg.name}_logits",
+                                   lambda p, x: tf._logits(p, cfg, x))
 
         # per-slot versions: layer staleness (bumped on EVERY kill) vs
         # ctrl staleness (bumped only on drop_ctrl retires — a miss must
@@ -1615,6 +1655,7 @@ class AsyncPipelineExecutor(PipelineExecutor):
         self._draft_pushed += 1
 
     # -- PipelineExecutor seam ------------------------------------------
+    @_traced
     def prefill(self, slot: int, prompt):
         """Admission prefill through the pipe (it has no prefill lane):
         the prompt is embedded on stage 0's device and rides the stages
@@ -1633,6 +1674,7 @@ class AsyncPipelineExecutor(PipelineExecutor):
             self._pump()
         return handle.resolve()
 
+    @_traced
     def tick_rows(self, tokens, positions, masks, model_len, write_idx,
                   row_on):
         """One engine timestep: push the queued ctrl message (if any),
@@ -1674,6 +1716,7 @@ class AsyncPipelineExecutor(PipelineExecutor):
         self._count("pipeline_tick")
         return d_all, handles
 
+    @_traced
     def verify_rows(self, tokens, positions, masks, model_len, write_idx,
                     row_on):
         """Standard seam, async semantics: (handles, d_all) with
@@ -1682,6 +1725,7 @@ class AsyncPipelineExecutor(PipelineExecutor):
                                         model_len, write_idx, row_on)
         return handles, d_all
 
+    @_traced
     def commit_rows(self, model_len, commit_mask) -> None:
         """Queue the target-side exit commit into the next ctrl message
         (it must trail the in-flight layers stage by stage); the draft
@@ -1701,6 +1745,7 @@ class AsyncPipelineExecutor(PipelineExecutor):
         self._ctrl_active = True
         self._submit_draft(("remap_row", int(slot), imap.copy()))
 
+    @_traced
     def remap_rows(self, index_maps, row_mask) -> None:
         rm = np.asarray(row_mask)
         if not rm.any():
