@@ -44,8 +44,13 @@ import numpy as np
 
 from repro.core import tree as tree_lib
 from repro.core.speculative import (ModelBundle, SamplingParams,
-                                    draft_candidates, remap_tree_caches,
-                                    select_token)
+                                    draft_candidates, named_jit,
+                                    remap_tree_caches, select_token)
+
+_draft_candidates_jit = named_jit("draft_candidates", draft_candidates,
+                                  static_argnames=("c",))
+_expand_tree_jit = named_jit("expand_tree", tree_lib.expand_from_draft,
+                             static_argnames=("w", "depth_cap"))
 
 
 @dataclasses.dataclass
@@ -117,13 +122,16 @@ def remap_flight_indices(node_idx: np.ndarray, index_map) -> np.ndarray:
 @dataclasses.dataclass
 class GenStats:
     """Per-request SpecPipe counters: timesteps, commits, hit/miss
-    verifications and ring entries.
+    verifications, ring entries, and tree layers grown or deferred at
+    the depth/capacity caps.
     """
     timesteps: int = 0
     commits: int = 0
     hits: int = 0
     misses: int = 0
     entries: int = 0
+    expanded: int = 0
+    expand_deferred: int = 0
 
     @property
     def acceptance(self) -> float:
@@ -293,43 +301,39 @@ class PipeDecEngine:
         st.pending = False
 
     # ---- phase 1c: tree expansion (may be deferred) ------------------
-    def can_expand(self, tree: tree_lib.Tree) -> bool:
-        """Depth-cap / buffer-capacity guard for appending one layer.  A
-        full layer appends ``width`` slots, so ``n_nodes + width`` must fit
-        within ``capacity`` NOW — admitting ``n_nodes + w == cap + 1``
-        (the old off-by-one) makes ``tree_expand`` silently truncate the
-        layer's last candidate at the buffer edge (pinned by the
-        capacity-saturation regression test)."""
-        p = self.pcfg
-        cur_depth = int(jnp.max(jnp.where(tree.valid(), tree.depth, 0)))
-        return (cur_depth < p.depth_cap
-                and int(tree.n_nodes) + p.width <= p.capacity)
+    @staticmethod
+    def wants_expand(st: DecodeState) -> bool:
+        """The request holds draft candidates for a layer still in the
+        tree: an expansion is due (it may be deferred at the caps)."""
+        return (st.last_draft is not None and not st.pending
+                and bool((st.last_draft[0] >= 0).any()))
+
+    @staticmethod
+    def record_expansion(st: DecodeState, grown: bool) -> None:
+        """Bookkeeping of one due expansion: a grown layer is pending
+        entry; a deferred one keeps its candidates and is retried next
+        timestep, once a prune frees room."""
+        if grown:
+            st.pending = True
+            st.last_draft = None
+            st.stats.expanded += 1
+        else:
+            st.stats.expand_deferred += 1
 
     def maybe_expand(self, st: DecodeState) -> None:
+        """Grow the request's tree by the layer its draft proposed at the
+        last entry.  The batched engine runs the same
+        ``tree_lib.expand_from_draft`` over every slot at once
+        (``core.dynbatch.expand_rows``)."""
+        if not self.wants_expand(st):
+            return
         p = self.pcfg
-        w, c = p.width, p.branch
-        if st.last_draft is None or st.pending:
-            return
-        if not self.can_expand(st.tree):
-            return  # deferred: retried next timestep once a prune frees room
         nidx, dlog = st.last_draft
-        rows_valid = nidx >= 0
-        if not rows_valid.any():
-            return
-        if hasattr(dlog, "resolve"):
-            # async backend: the draft actor's verify is a lazy future —
-            # block here (expansion is the first consumer of the logits)
-            dlog = dlog.resolve()
-        # surviving rows, in (compacted) index order, align with the
-        # deepest layer's slots
-        order = np.argsort(np.where(rows_valid, nidx,
-                                    np.iinfo(np.int32).max))
-        dlog_sorted = dlog[jnp.asarray(order)]
-        valid_sorted = jnp.asarray(rows_valid[order])
-        cand_tok, cand_lp = draft_candidates(dlog_sorted, valid_sorted, c)
-        st.tree = tree_lib.tree_expand(st.tree, cand_tok, cand_lp, w)
-        st.pending = True
-        st.last_draft = None
+        tok, lp = _draft_candidates_jit(dlog, jnp.ones(nidx.shape, bool),
+                                        c=p.branch)
+        st.tree, grown = _expand_tree_jit(st.tree, tok, lp, nidx, True,
+                                          w=p.width, depth_cap=p.depth_cap)
+        self.record_expansion(st, bool(grown))
 
     # ---- phase 2a: pick the exiting flight ---------------------------
     def exit_pick(self, st: DecodeState) -> Optional[Tuple[Flight, int]]:

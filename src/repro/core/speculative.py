@@ -262,10 +262,18 @@ def remap_tree_caches(tree_caches, index_map, capacity: int):
 def draft_candidates(logits: jnp.ndarray, valid: jnp.ndarray, c: int):
     """Per-node top-c candidates from draft logits.
 
-    logits: [w, V]; valid: [w].  Returns (cand_tokens [w,c],
-    cand_logprobs [w,c]) with invalid rows at -inf.
+    logits: [..., w, V]; valid: [..., w].  Returns (cand_tokens
+    [..., w, c], cand_logprobs [..., w, c]) with invalid rows at -inf.
+    The rows are flattened to one batch axis first: on a TPU ``top_k``
+    over a rank-3 operand compiles to a full sort of every row (28 ms
+    for 16 x 8 rows of 152064 on a v5e), over a matrix to XLA's own
+    top-k.
     """
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    shape = logits.shape
+    logp = jax.nn.log_softmax(
+        logits.reshape(-1, shape[-1]).astype(jnp.float32), axis=-1)
     top_lp, top_tok = jax.lax.top_k(logp, c)
-    top_lp = jnp.where(valid[:, None], top_lp, tree_lib.NEG_INF)
+    top_lp = top_lp.reshape(*shape[:-1], c)
+    top_tok = top_tok.reshape(*shape[:-1], c)
+    top_lp = jnp.where(valid[..., None], top_lp, tree_lib.NEG_INF)
     return top_tok.astype(jnp.int32), top_lp

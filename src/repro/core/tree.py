@@ -129,6 +129,39 @@ def tree_expand(tree: Tree, cand_tokens: jnp.ndarray,
                 layer_start=start, layer_size=new_size)
 
 
+def can_grow(tree: Tree, w: int, depth_cap: int) -> jnp.ndarray:
+    """Whether one more ``w``-wide layer may be appended: the deepest
+    node lies above ``depth_cap`` and ``n_nodes + w`` fits the buffer
+    NOW (``n_nodes + w == capacity + 1`` would make ``tree_expand``
+    truncate the layer's last candidate at the buffer edge)."""
+    cur_depth = jnp.max(jnp.where(tree.valid(), tree.depth, 0))
+    return (cur_depth < depth_cap) & (tree.n_nodes + w <= tree.capacity)
+
+
+def expand_from_draft(tree: Tree, cand_tokens: jnp.ndarray,
+                      cand_logprobs: jnp.ndarray, node_idx: jnp.ndarray,
+                      on, w: int, depth_cap: int) -> Tuple[Tree, jnp.ndarray]:
+    """Grow the layer that entered with ``node_idx`` from its draft
+    candidates, if ``on`` and the caps allow (``can_grow``).
+
+    ``cand_tokens``/``cand_logprobs`` [w, c] are the top-c draft
+    candidates of each entered row, in entry order, and ``node_idx`` [w]
+    the rows' tree indices after any prune since (-1: row invalid or
+    pruned away).  The surviving rows, sorted by index, align with the
+    deepest layer's nodes.  Returns (tree, grown): the old tree where
+    nothing grows.  The one definition of an expansion, run per request
+    (``PipeDecEngine.maybe_expand``) and vmapped over the slot-stacked
+    trees (``core.dynbatch.expand_rows``)."""
+    valid = node_idx >= 0
+    order = jnp.argsort(jnp.where(valid, node_idx,
+                                  jnp.iinfo(jnp.int32).max))
+    lp = jnp.where(valid[order][:, None], cand_logprobs[order], NEG_INF)
+    grown = tree_expand(tree, cand_tokens[order], lp, w)
+    ok = jnp.asarray(on) & can_grow(tree, w, depth_cap)
+    return jax.tree.map(lambda new, old: jnp.where(ok, new, old),
+                        grown, tree), ok
+
+
 def find_child_with_token(tree: Tree, token, parent_idx=0) -> jnp.ndarray:
     """hit_index (paper §3.3.4): node index of the child of ``parent_idx``
     whose token equals ``token``; -1 on miss."""

@@ -39,11 +39,16 @@ pin it.  Wall-clock is priced in ``core.sim.specpipe_db_*`` /
 curve, measured).
 
 Per-request *decisions* (flight bookkeeping, token selection with
-per-request ``SamplingParams``, tree expand/prune, index remaps) run
-through the same ``PipeDecEngine`` phase methods (gather-entry /
-apply-fused / exit-commit) the single-request engine uses — that engine is
-literally the B=1 case of this code — so each request's operation trace is
-identical to running it alone.
+per-request ``SamplingParams``, tree prune, index remaps) run through the
+same ``PipeDecEngine`` phase methods (gather-entry / apply-fused /
+exit-commit) the single-request engine uses — that engine is literally
+the B=1 case of this code — so each request's operation trace is
+identical to running it alone.  Tree expansion runs for every slot at
+once, in one compiled program over the slot-stacked ``TreeBatch``
+(``expand_rows``), which vmaps the per-tree function the single-request
+``maybe_expand`` runs.  Between entry and exit the ``TreeBatch`` holds
+the only copy of each slot's tree; a slot's tree is taken out only to
+apply its exit.
 
 Scheduling per global timestep:
   1. refill — admit arrived requests (priority/aging order, FIFO when
@@ -56,7 +61,7 @@ Scheduling per global timestep:
      ``n_stages - 1`` ticks later, when its ``DecodeState`` is seeded
      from the resolved ``DeferredPrefill`` logits;
   2. advance — gather every active request's entry, run the fused verify,
-     then expansion and (batched-commit) exit per slot;
+     then the batched expansion and the (batched-commit) exit per slot;
   3. retire — requests that hit eos or their token budget release their
      slot (retire-on-eos) for the next refill.
 
@@ -139,7 +144,9 @@ class DBStats:
     path.  ``hits`` / ``misses`` count the same decisions live, as each
     exit decides, and ``tokens_committed`` every committed token (each
     request's first, picked from its prefill, included) as it commits:
-    they read mid-run, before any request retires.
+    they read mid-run, before any request retires.  ``expanded`` counts
+    slots whose tree grew a layer and ``expand_deferred`` slots that held
+    draft candidates but met the depth or capacity cap, live as well.
     ``separate_prefill_dispatches`` counts admissions that ran a
     standalone ``executor.prefill`` dispatch instead of riding the ring's
     (chunked) prefill lane — exactly 0 on the overlapped backend at ANY
@@ -153,6 +160,8 @@ class DBStats:
     hits: int = 0
     misses: int = 0
     tokens_committed: int = 0
+    expanded: int = 0
+    expand_deferred: int = 0
     per_request: Dict[int, GenStats] = dataclasses.field(default_factory=dict)
     occupancy: List[int] = dataclasses.field(default_factory=list)
     verify_dispatches: List[int] = dataclasses.field(default_factory=list)
@@ -319,17 +328,21 @@ class SpecPipeDBEngine:
             self.inner.apply_entry(active[slot].state, entry,
                                    v_of(slot), d_all[slot])
 
-    def _fused_entry(self, active: Dict[int, _Active],
-                     pending: List[int]) -> None:
+    def _fused_entry(self, active: Dict[int, _Active], stepping: List[int],
+                     pending: List[int]):
         """Hand the executor ONE bucketed verify per model over the
-        stacked entry rows and scatter the logits back through
-        ``apply_entry``."""
+        stacked entry rows, launch the expansion behind it, and scatter
+        the logits back through ``apply_entry``.  Returns the launched
+        expansion."""
         rows = self._entry_rows(active, pending)
-        tokens, positions, masks, mlen, wi, row_on, _ = rows
+        tokens, positions, masks, mlen, wi, row_on, node_idx_b = rows
         v_all, d_all = self.executor.verify_rows(tokens, positions, masks,
                                                  mlen, wi, row_on)
+        launched = self._launch_expand(active, stepping, d_all, row_on,
+                                       node_idx_b)
         self._apply_entries(active, pending, rows,
                             lambda slot: v_all[slot], d_all)
+        return launched
 
     # -- shared per-timestep phases ------------------------------------
     def _bump(self, active: Dict[int, _Active],
@@ -338,7 +351,6 @@ class SpecPipeDBEngine:
             st = active[slot].state
             st.t += 1
             st.stats.timesteps = st.t
-            st.tree = self.trees.get_row(slot)
         return [s for s in stepping if active[s].state.pending]
 
     def _pick_exits(self, active: Dict[int, _Active],
@@ -371,25 +383,27 @@ class SpecPipeDBEngine:
         slot's in-flight ring layers — the pruning-propagation stage."""
         remaps: Dict[int, np.ndarray] = {}
         for slot in stepping:
+            if slot not in picks:
+                continue
             a = active[slot]
             st = a.state
-            if slot in picks:
-                with TraceAnnotation("specpipe.exit.slot", uid=a.req.uid,
-                                     slot=slot):
-                    fl, root_row = picks[slot]
-                    misses0 = st.stats.misses
-                    self.stats.tokens_committed += self.inner.exit_apply(
-                        st, fl, root_row,
-                        commit_caches=lambda _st: None,  # batched above
-                        remap_caches=lambda _st, imap, s=slot:
-                            remaps.__setitem__(s, imap))
-                    missed = st.stats.misses > misses0
-                    self.stats.misses += missed
-                    self.stats.hits += not missed
-                    if kill_stale and missed:
-                        self.executor.kill(slot)
-            self.trees.set_row(slot, st.tree)
-            st.tree = None
+            with TraceAnnotation("specpipe.exit.slot", uid=a.req.uid,
+                                 slot=slot):
+                fl, root_row = picks[slot]
+                misses0 = st.stats.misses
+                st.tree = self.trees.get_row(slot)
+                self.stats.tokens_committed += self.inner.exit_apply(
+                    st, fl, root_row,
+                    commit_caches=lambda _st: None,  # batched above
+                    remap_caches=lambda _st, imap, s=slot:
+                        remaps.__setitem__(s, imap))
+                self.trees.set_row(slot, st.tree)
+                st.tree = None
+                missed = st.stats.misses > misses0
+                self.stats.misses += missed
+                self.stats.hits += not missed
+                if kill_stale and missed:
+                    self.executor.kill(slot)
         if remaps:
             imaps = np.tile(np.arange(self.pcfg.capacity, dtype=np.int32),
                             (self.max_slots, 1))
@@ -403,7 +417,7 @@ class SpecPipeDBEngine:
     def _advance_fused(self, active: Dict[int, _Active],
                        stepping: List[int]) -> None:
         """One shared pipeline timestep over all stepping slots: gather
-        entries → ONE fused verify per model → per-slot expansion →
+        entries → ONE fused verify per model → ONE batched expansion →
         batched commit → batched prune/remap."""
         # phase 1: stacked gather-entry, ONE fused verify per model (the
         # pending flag alone decides participation — the entry inputs come
@@ -411,10 +425,14 @@ class SpecPipeDBEngine:
         with TraceAnnotation("specpipe.entry"):
             pending = self._bump(active, stepping)
             if pending:
-                self._fused_entry(active, pending)
+                launched = self._fused_entry(active, stepping, pending)
+            else:
+                launched = self._launch_expand(
+                    active, stepping, None,
+                    np.zeros((self.max_slots,), bool), None)
             self.stats.verify_dispatches.append(1 if pending else 0)
 
-        self._expand(active, stepping)
+        self._expand(active, stepping, launched)
 
         # phase 2: exit — batched commit, then batched prune/remap
         with TraceAnnotation("specpipe.exit"):
@@ -422,15 +440,55 @@ class SpecPipeDBEngine:
             self._commit_exits(active, picks)
             self._apply_exits(active, stepping, picks)
 
-    def _expand(self, active: Dict[int, _Active],
-                stepping: List[int]) -> None:
-        """Expansion per slot (tree ops only; may defer at the caps)."""
+    def _launch_expand(self, active: Dict[int, _Active],
+                       stepping: List[int], d_all, row_on, node_idx_b):
+        """Enqueue ONE compiled expansion over every slot's tree
+        (``TreeBatch.expand_rows``) right behind this timestep's verify,
+        so it runs on the device while the host applies the entries.
+        Due are the slots that entered now (``row_on``, with their entry
+        ``node_idx_b``) and those still holding candidates from an
+        earlier entry (deferred at the caps).  ``d_all``: the draft
+        verify logits (None when nothing entered; the async backend's
+        future is waited on here, its first consumer).  Returns (due
+        [slots], grown [slots] on the device, or None)."""
+        p = self.pcfg
+        due = np.zeros((self.max_slots,), bool)
+        nidx = np.full((self.max_slots, p.width), -1, np.int32)
+        for slot in stepping:
+            st = active[slot].state
+            if row_on[slot]:
+                idx = node_idx_b[slot]
+            elif self.inner.wants_expand(st):
+                idx = st.last_draft[0]
+            else:
+                continue
+            due[slot] = bool((idx >= 0).any())
+            nidx[slot] = idx
+        if hasattr(d_all, "resolve"):
+            d_all = d_all.resolve()
+        if d_all is None and not due.any():
+            return due, None
+        return due, self.trees.expand_rows(
+            d_all, row_on, nidx, due, w=p.width, c=p.branch,
+            depth_cap=p.depth_cap)
+
+    def _expand(self, active: Dict[int, _Active], stepping: List[int],
+                launched) -> None:
+        """The launched expansion's bookkeeping: one host read of which
+        slots grew (each may defer at the caps), then per slot."""
         with TraceAnnotation("specpipe.expand"):
+            due, grown = launched
+            if grown is not None:
+                grown = np.asarray(grown)
             for slot in stepping:
                 a = active[slot]
                 with TraceAnnotation("specpipe.expand.slot", uid=a.req.uid,
                                      slot=slot):
-                    self.inner.maybe_expand(a.state)
+                    if due[slot]:
+                        self.inner.record_expansion(a.state,
+                                                    bool(grown[slot]))
+                        self.stats.expanded += bool(grown[slot])
+                        self.stats.expand_deferred += not grown[slot]
 
     # ------------------------------------------------------------------
     def _advance_overlapped(self, active: Dict[int, _Active],
@@ -452,7 +510,7 @@ class SpecPipeDBEngine:
             else:
                 rows = (*self.executor.dead_entry,
                         np.zeros((self.max_slots,), bool), None)
-            tokens, positions, masks, mlen, wi, row_on, _ = rows
+            tokens, positions, masks, mlen, wi, row_on, node_idx_b = rows
 
             # phase 1: ONE ring tick — entry for t in, exit for
             # t - (n_stages - 1) out
@@ -460,10 +518,12 @@ class SpecPipeDBEngine:
                 tokens, positions, masks, mlen, wi, row_on)
             self.stats.verify_dispatches.append(1 if pending else 0)
             self.stats.tick_dispatches.append(1)
+            launched = self._launch_expand(active, stepping, d_all, row_on,
+                                           node_idx_b)
             self._apply_entries(active, pending, rows,
                                 lambda slot: handles[slot], d_all)
 
-        self._expand(active, stepping)
+        self._expand(active, stepping, launched)
 
         # phase 2: exit — this tick's resolved futures; cache sync rides
         # the NEXT tick's ctrl (draft applies immediately)
@@ -579,14 +639,18 @@ class SpecPipeDBEngine:
         else:
             for slot in stepping:
                 st = active[slot].state
-                before = (st.stats.hits, st.stats.misses, st.stats.commits)
+                before = dataclasses.replace(st.stats)
                 st.tree = self.trees.get_row(slot)
                 self.inner.step(st)
                 self.trees.set_row(slot, st.tree)
                 st.tree = None
-                self.stats.hits += st.stats.hits - before[0]
-                self.stats.misses += st.stats.misses - before[1]
-                self.stats.tokens_committed += st.stats.commits - before[2]
+                self.stats.hits += st.stats.hits - before.hits
+                self.stats.misses += st.stats.misses - before.misses
+                self.stats.tokens_committed += \
+                    st.stats.commits - before.commits
+                self.stats.expanded += st.stats.expanded - before.expanded
+                self.stats.expand_deferred += \
+                    st.stats.expand_deferred - before.expand_deferred
         self._stream(active, now, on_token)   # this timestep's commits
 
         # 3. retire: free slots for the next refill (fused mode: the

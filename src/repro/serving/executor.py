@@ -1143,10 +1143,10 @@ class _AsyncDeferredLogits(DeferredLogits):
 
 class _DraftVerifyResult:
     """Future for one timestep's batched draft proposal logits
-    ([bucket, w, V]), filled by the draft actor.  ``__getitem__`` hands
-    the engine a per-slot ``resolve()``-able row (the lazy counterpart
-    of slicing the eager array), which ``PipeDecEngine.maybe_expand``
-    resolves right before expanding the tree."""
+    ([bucket, w, V]), filled by the draft actor.  The engine's batched
+    expansion, its first consumer, waits on it once (``resolve``);
+    ``__getitem__`` hands ``apply_entry`` a per-slot ``resolve()``-able
+    row (the lazy counterpart of slicing the eager array)."""
 
     __slots__ = ("_ex", "_event", "_value")
 
@@ -1167,6 +1167,8 @@ class _DraftVerifyResult:
                     f"timed out after {self._ex.timeout_s}s waiting for "
                     f"the draft actor's verify")
         return self._value
+
+    resolve = wait
 
 
 class _DeferredDraftRow:

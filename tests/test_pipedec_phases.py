@@ -76,23 +76,22 @@ def test_tree_expand_truncates_at_capacity():
         "layer silently truncated at the buffer edge"
 
 
-def test_expansion_guard_defers_at_saturation(bundles):
+def test_expansion_guard_defers_at_saturation():
     """The engine guard admits a layer only when all ``w`` slots fit:
     ``n_nodes + w <= cap`` expands, ``n_nodes + w == cap + 1`` defers
     (the old ``<= cap + 1`` guard admitted the truncating expand above)."""
-    target, draft = bundles
     w = 2
     pcfg = PipeDecConfig(n_stages=2, width=w, branch=2, max_depth=6)
     cap = pcfg.capacity
-    eng = PipeDecEngine(target, draft, pcfg)
+    can_expand = lambda t: bool(tree_lib.can_grow(t, w, pcfg.depth_cap))
     tree = tree_lib.tree_init(cap, 3)
 
     ok = tree._replace(n_nodes=jnp.asarray(cap - w, jnp.int32))
-    assert eng.can_expand(ok)
+    assert can_expand(ok)
     exact = tree._replace(n_nodes=jnp.asarray(cap + 1 - w, jnp.int32))
-    assert not eng.can_expand(exact), "off-by-one: truncating expand admitted"
+    assert not can_expand(exact), "off-by-one: truncating expand admitted"
     full = tree._replace(n_nodes=jnp.asarray(cap, jnp.int32))
-    assert not eng.can_expand(full)
+    assert not can_expand(full)
 
 
 def test_deep_tree_small_capacity_stays_lossless(bundles):
