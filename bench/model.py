@@ -1,77 +1,94 @@
-"""A configuration file, turned into shapes, seeded weights and the
-program's ``ModelConfig``.
+"""A configuration file, its model families, and the weights they make.
 
 A configuration file (``bench/configs/<name>.json``) holds the target's
 published ``config.json`` keys at its top level, the draft's under
-``draft`` and the serving settings under ``serving``.  ``Shape`` is the
-benchmark's own view of one model; the reference (``reference.py``) and
-the FLOP counts (``flops.py``) read it, never the program's config.
+``draft`` and the serving settings under ``serving``.  Each model's
+published ``model_type`` names its family: the module
+``bench/families/<model_type>.py``, which builds, serves, checks and
+prices that architecture.  A family gives:
 
-Weights are made here, from the seed, on the device, in the parameter
-layout ``repro.models.transformer`` serves from.  The reference reads the
-same arrays: they are the benchmark's, not the program's.
+- ``Shape``: the benchmark's own view of one model (frozen, hashable,
+  ``Shape.from_json(keys)``, with at least ``layers``, ``d`` and
+  ``vocab``); the reference and the counts read it, never the
+  program's config;
+- ``make_local(seed_key, shape, device) -> Weights``: weights made from
+  the seed on the device, in the layout the program serves from, as
+  much of each layer as one chip holds;
+- ``program_config(shape, name)``: the program's ``ModelConfig``;
+- ``hidden(weights, tokens, precision)`` and ``head(weights, x,
+  precision)``: the plain reference's layer walk and its final norm and
+  head (``reference.py`` pads, blocks the rows and compares);
+- ``verify_call(shape, layers, nodes, context, kv_rows) -> (flops,
+  bytes)`` and ``prefill_flops(shape, n)`` (``flops.py``).
+
+So a new architecture is a configuration file and a family module.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import sys
+import types
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
+# directories searched, in order, for <model_type>.py
+FAMILIES = [os.path.join(BENCH, "families")]
 
 
-@dataclasses.dataclass(frozen=True)
-class Shape:
-    """One dense Qwen2-style decoder as published (widths never cut)."""
+def family(c: dict, where: str) -> types.ModuleType:
+    """The family module of one model's ``config.json`` keys ``c``: the
+    first ``<model_type>.py`` on ``FAMILIES``.  ``where`` names the keys
+    in an error (a file, and whether the draft's)."""
+    name = c.get("model_type")
+    if name is None:
+        raise LookupError(f"{where}: no \"model_type\" key, so no "
+                          f"bench/families/<model_type>.py to look for")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise LookupError(f"{where}: model_type {name!r} names no module")
+    paths = [os.path.join(d, f"{name}.py") for d in FAMILIES]
+    path = next((p for p in paths if os.path.isfile(p)), None)
+    if path is None:
+        raise LookupError(f"{where}: model_type {name!r} has no family "
+                          f"module; looked for {', '.join(paths)}")
+    modname = f"bench_family_{name}"
+    mod = sys.modules.get(modname)
+    if mod is None or mod.__file__ != path:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[modname] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[modname]
+            raise
+    return mod
 
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    ff: int
-    vocab: int
-    eps: float
-    theta: float
-    tied: bool
 
-    @property
-    def hd(self) -> int:
-        return self.d // self.heads
-
-    @classmethod
-    def from_json(cls, c: dict) -> "Shape":
-        return cls(layers=c["num_hidden_layers"], d=c["hidden_size"],
-                   heads=c["num_attention_heads"],
-                   kv_heads=c["num_key_value_heads"],
-                   ff=c["intermediate_size"], vocab=c["vocab_size"],
-                   eps=float(c["rms_norm_eps"]), theta=float(c["rope_theta"]),
-                   tied=bool(c["tie_word_embeddings"]))
+def families(conf: dict, where: str = "the configuration"):
+    """(target family, draft family) of a configuration file."""
+    return family(conf, where), family(conf["draft"], f"{where} [draft]")
 
 
 def load_config(name: str) -> dict:
-    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
-        return json.load(f)
+    """``bench/configs/<name>.json``; a model whose family is missing or
+    unknown fails here, naming the file."""
+    path = os.path.join(BENCH, "configs", f"{name}.json")
+    with open(path) as f:
+        conf = json.load(f)
+    families(conf, path)
+    return conf
 
 
 def shapes(conf: dict):
-    """(target Shape, draft Shape) of a configuration file."""
-    return Shape.from_json(conf), Shape.from_json(conf["draft"])
-
-
-def program_config(s: Shape, name: str):
-    """The program's ``ModelConfig`` for a shape (fp32, dense, QKV bias,
-    SwiGLU: the Qwen2 block)."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(name=name, family="dense", num_layers=s.layers,
-                       d_model=s.d, num_heads=s.heads,
-                       num_kv_heads=s.kv_heads, d_ff=s.ff,
-                       vocab_size=s.vocab, mlp_variant="swiglu",
-                       qkv_bias=True, tie_embeddings=s.tied, norm_eps=s.eps,
-                       rope_theta=s.theta, dtype="float32")
+    """(target Shape, draft Shape) of a configuration file, each its
+    family's."""
+    return tuple(fam.Shape.from_json(c) for fam, c in
+                 zip(families(conf), (conf, conf["draft"])))
 
 
 def seed_key(seed: int):
@@ -83,70 +100,21 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
-def _normal(key, shape, scale):
-    return jax.random.normal(key, shape, jnp.float32) * scale
-
-
-def init_head(key, s: Shape):
-    """Embedding, final norm and (untied) output head."""
-    k = jax.random.split(key, 3)
-    out = {"embed": {"table": _normal(k[0], (s.vocab, s.d), 0.02)},
-           "final_norm": {"scale": 1.0 + _normal(k[1], (s.d,), 0.1)}}
-    if not s.tied:
-        out["lm_head"] = {"table": _normal(k[2], (s.vocab, s.d), 0.02)}
-    return out
-
-
-def init_layers(key, s: Shape, n: int):
-    """``n`` decoder layers stacked on a leading axis, as the program's
-    ``params["stack"]`` holds them (a list of one unit).  Biases and norm
-    scales are random too, so the reference checks that they are read."""
-    d, h, kv, hd, ff = s.d, s.heads, s.kv_heads, s.hd, s.ff
-    k = jax.random.split(key, 11)
-    mixer = {
-        "w_q": _normal(k[0], (n, d, h, hd), d ** -0.5),
-        "w_k": _normal(k[1], (n, d, kv, hd), d ** -0.5),
-        "w_v": _normal(k[2], (n, d, kv, hd), d ** -0.5),
-        "w_o": _normal(k[3], (n, h, hd, d), (h * hd) ** -0.5),
-        "b_q": _normal(k[4], (n, h, hd), 0.1),
-        "b_k": _normal(k[5], (n, kv, hd), 0.1),
-        "b_v": _normal(k[6], (n, kv, hd), 0.1),
-    }
-    ffn = {"w_gate": _normal(k[7], (n, d, ff), d ** -0.5),
-           "w_up": _normal(k[8], (n, d, ff), d ** -0.5),
-           "w_down": _normal(k[9], (n, ff, d), ff ** -0.5)}
-    norms = _normal(k[10], (2, n, d), 0.1) + 1.0
-    return [{"norm1": {"scale": norms[0]}, "mixer": mixer,
-             "norm2": {"scale": norms[1]}, "ffn": ffn}]
-
-
-def init_model(key, s: Shape):
-    """A whole model on one device: ``{"embed", "final_norm",
-    ["lm_head"], "stack"}``."""
-    kh, kl = jax.random.split(key)
-    return {**init_head(kh, s), "stack": init_layers(kl, s, s.layers)}
-
-
 @dataclasses.dataclass
 class Weights:
     """The benchmark's weights of one model and where they live.
 
-    ``params`` is what the program is handed.  ``stages`` lists, per
-    device, that device's layers (a stack tree with a leading layer
-    axis), so the reference runs layer by layer where the layers already
-    are (one entry on one chip; one per stage for a pipeline placement)."""
+    ``family`` is the module that made them (``model.family``), and
+    ``shape`` its ``Shape``.  ``params`` is what the program is handed.
+    ``stages`` lists, per device, that device's layers (a stack tree with
+    a leading layer axis), so the reference runs layer by layer where the
+    layers already are (one entry on one chip; one per stage for a
+    pipeline placement)."""
 
-    shape: Shape
+    family: types.ModuleType
+    shape: object
     params: dict
     stages: list
-
-
-def make_local(seed_k, s: Shape, device) -> Weights:
-    """The whole model on ``device``, made by one compiled program."""
-    sharding = jax.sharding.SingleDeviceSharding(device)
-    params = jax.jit(init_model, static_argnums=1,
-                     out_shardings=sharding)(seed_k, s)
-    return Weights(s, params, [(device, params["stack"])])
 
 
 def nbytes(tree) -> int:

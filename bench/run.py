@@ -132,13 +132,16 @@ def programs(clock) -> int:
 
 
 def make_weights(conf: dict, seed: int, devices):
+    """(target, draft) ``model.Weights`` from the seed, each made by its
+    family on the first device."""
     import jax
 
     import model
-    ts, ds = model.shapes(conf)
     key = model.seed_key(seed)
-    target = model.make_local(jax.random.fold_in(key, 1), ts, devices[0])
-    draft = model.make_local(jax.random.fold_in(key, 2), ds, devices[0])
+    target, draft = (
+        fam.make_local(jax.random.fold_in(key, i), s, devices[0])
+        for i, fam, s in zip((1, 2), model.families(conf),
+                             model.shapes(conf)))
     jax.block_until_ready((target.params, draft.params))
     return target, draft
 
@@ -377,11 +380,11 @@ def run_cell(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
                    for d in devices)
     kind = devices[0].device_kind
     peak = fl.peaks(kind)
-    shapes = (target_w.shape, draft_w.shape, peak)
-    useful, _, _ = serve.verify_work(feed.calls, *shapes, feed.mark,
+    models = (target_w, draft_w, peak)
+    useful, _, _ = serve.verify_work(feed.calls, *models, feed.mark,
                                      feed.t_close)
     _, least, least_calls = serve.verify_work(
-        feed.calls, *shapes, feed.t_open, state.get("traced_to", feed.t_open))
+        feed.calls, *models, feed.t_open, state.get("traced_to", feed.t_open))
     late = feed.lateness_ms()
     step_ms, step_at = feed.longest_timestep()
     in_gc = [(d, g) for t, d, g in pauses if t >= feed.t_open]

@@ -76,13 +76,13 @@ def build(conf: dict, target_w, draft_w):
     from repro.core.speculative import ModelBundle
     from repro.serving import LocalFusedExecutor
 
-    from model import program_config
     sv = conf["serving"]
     pcfg = PipeDecConfig(n_stages=sv["n_stages"], width=sv["width"],
                          branch=sv["branch"])
-    target = ModelBundle(target_w.params,
-                         program_config(target_w.shape, "target"))
-    draft = ModelBundle(draft_w.params, program_config(draft_w.shape, "draft"))
+    target, draft = (ModelBundle(w.params, w.family.program_config(w.shape,
+                                                                    role))
+                     for role, w in (("target", target_w),
+                                     ("draft", draft_w)))
     kw = dict(slots=sv["slots"], max_len=sv["max_len"],
               tree_capacity=pcfg.tree_buffer_capacity,
               capacity=pcfg.capacity)
@@ -442,9 +442,10 @@ def percentile(values, q: float) -> Optional[float]:
 def verify_work(calls: CallLog, target, draft, peak: dict, lo: float,
                 hi: float):
     """(useful FLOPs, {role: least seconds}, {role: verify calls}) of the
-    logged calls made between ``lo`` and ``hi``.  Useful work counts the
-    valid tree nodes that the target verifies and the draft proposes
-    from, and each prompt token prefilled."""
+    logged calls made between ``lo`` and ``hi``, counted by each model's
+    family from its shape (``target``, ``draft``: ``model.Weights``).
+    Useful work counts the valid tree nodes that the target verifies and
+    the draft proposes from, and each prompt token prefilled."""
     useful = 0.0
     least = collections.defaultdict(float)
     n_calls = collections.Counter()
@@ -457,13 +458,14 @@ def verify_work(calls: CallLog, target, draft, peak: dict, lo: float,
         nodes = int(on.sum())
         context = float((ctx[:, None] * on).sum() + anc.sum())
         kv_rows = float(ctx[on.any(-1)].sum() + anc.sum() + nodes)
-        s = draft if role == "draft" else target
-        f, b = fl.verify_call(s, s.layers, nodes, context, kv_rows)
+        w = draft if role == "draft" else target
+        f, b = w.family.verify_call(w.shape, w.shape.layers, nodes, context,
+                                    kv_rows)
         useful += f
         least[role] += fl.least_time(f, b, peak)
         n_calls[role] += 1
     for t, n in calls.prefill:
         if lo <= t <= hi:
-            useful += fl.prefill_flops(target, n) + fl.prefill_flops(draft,
-                                                                     n)
+            useful += sum(w.family.prefill_flops(w.shape, n)
+                          for w in (target, draft))
     return useful, dict(least), dict(n_calls)

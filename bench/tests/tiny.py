@@ -8,7 +8,7 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 
-SHAPE = {"hidden_size": 64, "intermediate_size": 128,
+SHAPE = {"model_type": "qwen2", "hidden_size": 64, "intermediate_size": 128,
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "num_hidden_layers": 2, "vocab_size": 512, "rms_norm_eps": 1e-6,
          "rope_theta": 1e6, "tie_word_embeddings": False}
